@@ -12,7 +12,7 @@ Two configs ride this module:
     invalidated TTL cache converting the flood into one producer
     lookup per window), and carries the streamed-bytes/chunks totals.
   * ``lcproof`` — the batched device Merkle-proof kernel
-    (ops/merkle_proof) at BENCH_NSETS query shapes (the watcher sweeps
+    (ops/merkle_proof) at BENCH_NSETS query shapes (the chip runs
     1k/16k): deterministic (leaf, branch, gindex) queries at the
     light-client finality depth, device results cross-checked
     byte-identical against the hashlib host oracle every iteration.

@@ -2,9 +2,9 @@
 
 Tier-1's wall clock is DOMINATED by cold XLA compiles (ROADMAP:
 cold-compile cost decides which tests fit the 870 s window; PR 8's
-headline was a 598.5 s -> 6.9 s cold-compile drop), and `tpu_watcher`
-sweeps pay a fresh compile per config — but until now the only record
-was log archaeology over bench stdout. This module is the structured
+headline was a 598.5 s -> 6.9 s cold-compile drop), and every chip run
+pays a fresh compile per shape — but until now the only record was log
+archaeology over bench stdout. This module is the structured
 replacement: every device dispatch through the bls/kzg/sharded backends
 records one ledger entry
 
@@ -22,8 +22,7 @@ The ledger is PROCESS-GLOBAL (compiles are a property of the process's
 jit caches, not of any one chain) and served at ``GET
 /lighthouse/compiles``. Set ``LIGHTHOUSE_TPU_COMPILE_LEDGER=/path`` (or
 call `LEDGER.configure(path=...)`; `bn --compile-ledger` wires the
-flag) to ALSO append every COLD entry to a persistent JSONL file — the
-artifact `scripts/tpu_watcher.py` attaches to each sweep measurement.
+flag) to ALSO append every COLD entry to a persistent JSONL file.
 Warm dispatches stay in the ring and the counters only: a bench loop
 dispatches thousands of warm reps inside its timed region, and a
 per-dispatch open/append would inflate exactly the p50/p99 the sweep
@@ -141,24 +140,12 @@ class CompileLedger:
         impl_key,
         shape: str,
         duration_s: float | None = None,
-    ):
+    ) -> int:
         """Record one dispatch through `jitted`, classifying cold/warm
         from its trace-cache growth. Returns the number of NEW traces
         this dispatch compiled (0 == warm) — the bls backend feeds its
-        jit_cache_events xla layer from this return. Version-tolerant:
-        a jax without `_cache_size` cannot classify — the entry records
-        event='unknown' and the return is None so callers' cache-hit
-        metrics go dark instead of fabricating hits."""
-        try:
-            size = jitted._cache_size()
-        # lint: allow(except-swallow): version probe — no _cache_size on older jax, classification goes dark
-        except Exception:
-            size = None
-        if size is None:
-            self.record(
-                fn, impl_key, shape, "unknown", duration_s=duration_s
-            )
-            return None
+        jit_cache_events xla layer from this return."""
+        size = jitted._cache_size()
         grew = 0
         key = (fn, id(jitted))
         with self._lock:
@@ -174,6 +161,21 @@ class CompileLedger:
             duration_s=duration_s,
         )
         return grew
+
+    def note_compile(
+        self, fn: str, jitted, impl_key, shape: str, duration_s: float
+    ):
+        """Record an ahead-of-dispatch compile (`jitted.lower().compile()`)
+        as the bucket's cold entry. The dispatch that follows reuses the
+        executable but still adds one entry to the jit's trace cache;
+        that growth is booked here (one per compile, however many are
+        compiled before their dispatches) so the dispatch records warm."""
+        key = (fn, id(jitted))
+        with self._lock:
+            self._cache_sizes[key] = (
+                max(self._cache_sizes.get(key, 0), jitted._cache_size()) + 1
+            )
+        return self.record(fn, impl_key, shape, "cold", duration_s)
 
     # ------------------------------------------------------------- reads
 
@@ -203,8 +205,8 @@ class CompileLedger:
 
 
 def load_jsonl(path) -> list:
-    """Read a persisted ledger file back into entry dicts (the watcher
-    and the round-trip test use this; malformed lines are skipped so a
+    """Read a persisted ledger file back into entry dicts (the
+    round-trip test uses this; malformed lines are skipped so a
     torn tail from a killed process can't break the reader)."""
     out = []
     try:

@@ -13,6 +13,9 @@ safe back to the host (the FPGA verification-engine posture, arxiv
                cannot be cancelled; the reaper joins them off the
                caller's critical path and counts late completions) and
                the caller fails over — callers always get a verdict.
+               Time the attempt spends inside `compile_window` (a
+               cold bucket's trace+compile) extends the deadline: the
+               watchdog times execution, not compilation.
   breaker    — per-(plane, shape-bucket) circuit breaker consulted
                before the device is touched; open means straight to
                failover, half-open admits one probe. Canary violations
@@ -498,14 +501,35 @@ class GuardedExecutor:
             else:
                 handle._resolve(result, None)
 
-    def _run_marked(self, device_fn, plan):
+    def _run_marked(self, device_fn, plan, window=None):
         """Invoke the attempt with this thread marked guard-active, so
-        nested guarded entry points pass through (see `dispatch`)."""
+        nested guarded entry points pass through (see `dispatch`);
+        `window` is the watchdog's compile-time record for it."""
         self._tls.active = True
+        self._tls.window = window
         try:
             return device_fn(plan)
         finally:
             self._tls.active = False
+            self._tls.window = None
+
+    @contextmanager
+    def compile_window(self):
+        """Mark trace+compile work inside a guarded attempt: the watchdog
+        extends its deadline by the time spent here, so a cold bucket's
+        compile (minutes for the TPU kernels) is never mistaken for a
+        wedged device while execution stays guarded. A no-op outside a
+        watchdog-timed attempt."""
+        window = getattr(self._tls, "window", None)
+        if window is None:
+            yield
+            return
+        window["open_at"] = time.monotonic()
+        try:
+            yield
+        finally:
+            window["compile_s"] += time.monotonic() - window["open_at"]
+            window["open_at"] = None
 
     def _attempt(
         self, plane, bucket, device_fn, plan, timeout_s, predicted_s,
@@ -517,10 +541,11 @@ class GuardedExecutor:
         if timeout_s is None:
             timeout_s = self.timeout_for(plane, bucket, predicted_s)
         box = {}
+        window = {"compile_s": 0.0, "open_at": None}
 
         def run():
             try:
-                box["result"] = self._run_marked(device_fn, plan)
+                box["result"] = self._run_marked(device_fn, plan, window)
             # lint: allow(except-swallow): watchdog thread trampoline — the exception is re-raised on the caller thread below
             except BaseException as exc:
                 box["error"] = exc
@@ -528,8 +553,20 @@ class GuardedExecutor:
         worker = threading.Thread(
             target=run, name=f"device-dispatch-{plane}", daemon=True
         )
+        t0 = time.monotonic()
         worker.start()
-        worker.join(timeout_s)
+        while worker.is_alive():
+            # time inside compile windows (closed or still open) does
+            # not count against the budget
+            open_at = window["open_at"]
+            now = time.monotonic()
+            compiling = window["compile_s"] + (
+                now - open_at if open_at is not None else 0.0
+            )
+            remaining = t0 + timeout_s + compiling - now
+            if remaining <= 0:
+                break
+            worker.join(min(remaining, 1.0))
         if worker.is_alive():
             self._abandon(worker, plane)
             raise DeviceTimeout(

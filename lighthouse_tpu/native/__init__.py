@@ -1,7 +1,8 @@
 """Native (C) components with pure-Python fallbacks.
 
-`build()` compiles the _hashtree extension in-place with the system
-toolchain (no pip); `hash_pairs` resolves to the native implementation when
+`build()` compiles the _hashtree extension in-place from hashtree.c with
+the system toolchain (no pip) whenever the source is newer than the
+build; `hash_pairs` resolves to the native implementation when
 the extension is present, else the hashlib fallback.
 """
 
@@ -19,12 +20,20 @@ def _so_path() -> str:
 
 
 def build(force: bool = False) -> bool:
-    """Compile the extension with cc; returns True on success."""
+    """Compile the extension with cc when it is missing or older than
+    its source; returns True when an up-to-date build exists. The build
+    writes a temporary file and renames it into place, so a process
+    importing concurrently never loads a half-written library."""
     so = _so_path()
     src = os.path.join(_HERE, "hashtree.c")
-    if os.path.exists(so) and not force:
+    try:
+        fresh = os.path.getmtime(so) >= os.path.getmtime(src)
+    except OSError:
+        fresh = False
+    if fresh and not force:
         return True
     include = sysconfig.get_paths()["include"]
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         os.environ.get("CC", "cc"),
         "-O3",
@@ -33,12 +42,13 @@ def build(force: bool = False) -> bool:
         f"-I{include}",
         src,
         "-o",
-        so,
+        tmp,
     ]
     try:
         subprocess.run(
             cmd, check=True, capture_output=True, timeout=120
         )
+        os.replace(tmp, so)
         return True
     # lint: allow(except-swallow): build probe; False selects the
     except Exception:  # pure-python fallback
@@ -46,18 +56,13 @@ def build(force: bool = False) -> bool:
 
 
 def _load():
+    if not build():
+        return None
     try:
         from lighthouse_tpu.native import _hashtree  # noqa: F401
 
         return _hashtree
     except ImportError:
-        if build():
-            try:
-                from lighthouse_tpu.native import _hashtree  # noqa: F811
-
-                return _hashtree
-            except ImportError:
-                return None
         return None
 
 

@@ -31,12 +31,16 @@ def build(force: bool = False) -> bool:
     if fresh and not force:
         return True
     cc = os.environ.get("CC", "cc")
-    base = [cc, "-O3", "-shared", "-fPIC", _SRC, "-o", _SO]
+    # a temporary output renamed into place: a concurrent importer never
+    # loads a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    base = [cc, "-O3", "-shared", "-fPIC", _SRC, "-o", tmp]
     # built on the machine that runs it, so native tuning is safe; fall
     # back to portable flags if the compiler rejects it
     for cmd in (base[:1] + ["-march=native"] + base[1:], base):
         try:
             subprocess.run(cmd, check=True, capture_output=True)
+            os.replace(tmp, _SO)
             return True
         except (subprocess.CalledProcessError, OSError):
             continue

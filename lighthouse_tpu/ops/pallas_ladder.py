@@ -33,6 +33,15 @@ from lighthouse_tpu.ops import window_ladder as wl
 
 NB = tf.NB
 
+# Scoped-VMEM budget for the Miller and final-exp kernels. In the bf16
+# MXU-REDC form (the on-TPU default) one 128-lane Miller block needs
+# ~21 MiB of scoped VMEM, over the compiler's 16 MiB default scope; v5e
+# has 128 MiB of physical VMEM. Raising the scope keeps the full 128-lane
+# block (halving block_b would leave half of every vreg idle) and the
+# kernel bodies as they are.
+VMEM_LIMIT_BYTES = 64 * 2**20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
 
 def _consts_array():
     return jnp.asarray(

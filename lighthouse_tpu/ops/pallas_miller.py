@@ -81,7 +81,10 @@ def miller_loop_pallas(
             memory_space=pltpu.VMEM,
         )
 
-    from lighthouse_tpu.ops.pallas_ladder import _consts_array
+    from lighthouse_tpu.ops.pallas_ladder import (
+        COMPILER_PARAMS,
+        _consts_array,
+    )
 
     consts = _consts_array()
     bits = jnp.asarray(_BITS)
@@ -105,6 +108,7 @@ def miller_loop_pallas(
             ),
         ],
         out_specs=spec(12),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(bits, px, py, qx, qy, consts, tf.redc_mats_array())
     if valid_mask is not None:

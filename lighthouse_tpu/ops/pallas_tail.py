@@ -31,7 +31,11 @@ from lighthouse_tpu.ops import tfield as tf
 NB = tf.NB
 
 
-from lighthouse_tpu.ops.pallas_ladder import _consts_array, _overrides
+from lighthouse_tpu.ops.pallas_ladder import (
+    COMPILER_PARAMS,
+    _consts_array,
+    _overrides,
+)
 
 
 def use_fused_tail() -> bool:
@@ -95,6 +99,7 @@ def final_exp_pallas(f1_t, interpret: bool = False):
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(
         pbits,

@@ -309,6 +309,59 @@ def make_grouped_signature_set_batch(
     return grouped, flat
 
 
+def make_api_signature_sets(
+    n_messages: int,
+    sets_per_message: int,
+    keys_per_set: int = 1,
+    seed: int = 0,
+):
+    """`bls.SignatureSet` objects for the node's API boundary
+    (`bls.api.verify_signature_sets`): `n_messages` distinct seeded
+    32-byte messages (real hash-to-curve), `sets_per_message` sets
+    each, every set signed by `keys_per_set` keys (one aggregate
+    signature). Secret keys are 1..N in set order and points are built
+    by running additions — O(N) group additions plus one short scalar
+    multiplication per message, as `fast_sequential` above — so the
+    30720-set mainnet slot builds in seconds. The signatures are NOT
+    pre-marked as subgroup-checked: verification pays that host check
+    as it would for gossip."""
+    from lighthouse_tpu.bls.api import PublicKey, Signature, SignatureSet
+    from lighthouse_tpu.bls.hash_to_curve import hash_to_g2
+
+    rng = random.Random(seed)
+    sets = []
+    running_pk = RG1.infinity
+    sk = 0  # last secret key handed out
+    for _ in range(n_messages):
+        message = rng.getrandbits(256).to_bytes(32, "big")
+        h = hash_to_g2(message)
+        sig_step = RG2.mul_scalar(h, keys_per_set)
+        # first set of this message: keys sk+1..sk+k sum to
+        # k*sk + k(k+1)/2; each next set's sum grows by k*k
+        agg_sk = keys_per_set * sk + keys_per_set * (keys_per_set + 1) // 2
+        sig = RG2.mul_scalar(h, agg_sk)
+        for _ in range(sets_per_message):
+            pks = []
+            for _ in range(keys_per_set):
+                running_pk = RG1.add(running_pk, RG1.generator)
+                pks.append(PublicKey(running_pk))
+            sets.append(SignatureSet(Signature(sig), pks, message))
+            for _ in range(keys_per_set):
+                sig = RG2.add(sig, sig_step)
+            sk += keys_per_set
+    return sets
+
+
+def forge_signature_set(sset):
+    """The same set with its signature moved off the valid point (the
+    message point added once): never valid for the set's keys."""
+    from lighthouse_tpu.bls.api import Signature, SignatureSet
+    from lighthouse_tpu.bls.hash_to_curve import hash_to_g2
+
+    bad = RG2.add(sset.signature.point, hash_to_g2(sset.message))
+    return SignatureSet(Signature(bad), sset.pubkeys, sset.message)
+
+
 def make_junk_attestation(t, spec, slot: int, tag: bytes):
     """A structurally-valid attestation that fails CHEAP stateful
     checks deterministically (committee index 63 is far out of range

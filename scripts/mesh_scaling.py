@@ -3,8 +3,7 @@
 Measures `parallel.sharded_verify` on the 8-device virtual CPU mesh:
 throughput vs device count along the "sets" axis, and the ring
 (recursive-doubling ppermute butterfly) vs gather+fold reduction, at a
-fixed GLOBAL batch size. Appends one JSON line per config to
-MULTICHIP_MEASUREMENTS.jsonl and prints a table.
+fixed GLOBAL batch size. Prints one JSON line per config and a table.
 
 Caveat recorded in every line: a virtual CPU mesh shares one socket's
 cores, so absolute numbers measure collective/program STRUCTURE (graph
@@ -24,7 +23,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-OUT = os.path.join(REPO, "MULTICHIP_MEASUREMENTS.jsonl")
 
 
 def main():
@@ -96,8 +94,7 @@ def main():
                 "git_head": git_head.strip(),
             }
             rows.append(rec)
-            with open(OUT, "a") as f:
-                f.write(json.dumps(rec) + "\n")
+            print(json.dumps(rec))
             print(
                 f"n={n} ring={int(ring)}: {rec['value']:>9} sigs/s "
                 f"(p50 {rec['p50_s']}s, compile {rec['compile_s']}s)"

@@ -394,7 +394,7 @@ def load_journal_jsonl(path) -> list:
     the report. (Near-twin of compile_ledger.load_jsonl, duplicated on
     purpose: this script stays importable standalone against any dump,
     and a user-passed --journal path that does not exist should raise,
-    where the watcher's maybe-absent ledger should not.)"""
+    where a maybe-absent ledger file should not.)"""
     out = []
     with open(path) as f:
         for line in f:

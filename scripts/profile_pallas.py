@@ -7,7 +7,7 @@ fused Miller kernel (stage B), and the XLA fold + final exponentiation
 tail (stage C). Writes one JSON line per stage to stdout and appends a
 combined record to PROFILE_PALLAS.jsonl.
 
-Run only when the watcher is idle (it owns the chip during sweeps):
+Run on the chip:
     python scripts/profile_pallas.py [S]
 """
 

@@ -43,10 +43,12 @@ def _mk_sets(n, shared_message=False, seed=0):
 
 @pytest.fixture
 def stub_dispatch(monkeypatch):
-    """Replace the device dispatch with an always-true stub; marshal
-    (bucketing, masks, waste accounting inputs) still runs for real."""
+    """Replace the device compile + dispatch with an always-true stub;
+    marshal (bucketing, masks, waste accounting inputs) still runs for
+    real."""
+    monkeypatch.setattr(tpu_backend, "_compile_ahead", lambda *a: 0.0)
     monkeypatch.setattr(
-        tpu_backend, "_dispatch", lambda m, rand_bits: np.True_
+        tpu_backend, "_dispatch", lambda m, program: np.True_
     )
 
 
@@ -135,6 +137,7 @@ def test_individual_fallback_attribution(monkeypatch):
     monkeypatch.setattr(
         tpu_backend, "_get_individual_fns", lambda: (stub, stub)
     )
+    monkeypatch.setattr(tpu_backend, "_compile_ahead", lambda *a: 0.0)
     j = Journal()
     before = _val(
         "lighthouse_tpu_device_batches_total",
@@ -178,8 +181,13 @@ def test_sharded_wrapper_attribution():
     from lighthouse_tpu.parallel.sharded_verify import _wrap_attributed
 
     calls = []
-    inner = lambda *a: calls.append(a) or np.True_  # noqa: E731
-    fn = _wrap_attributed(inner, "sharded_verify", "flat", "bench")
+
+    class _Inner(_FakeJit):  # a jitted verify, as the ledger reads it
+        def __call__(self, *a):
+            calls.append(a)
+            return np.True_
+
+    fn = _wrap_attributed(_Inner(), "sharded_verify", "flat", "bench")
     set_mask = np.array([True, True, False, False])
     before = _val(
         "lighthouse_tpu_device_batches_total", ("bench", "sharded", "4")
@@ -270,10 +278,18 @@ def test_compile_ledger_cold_warm_and_round_trip(tmp_path):
     # are the timed hot path and never pay file I/O)
     persisted = load_jsonl(str(path))
     assert persisted == [e for e in entries if e["event"] == "cold"]
-    # a jax without _cache_size cannot classify: 'unknown' entry, None
-    # return (callers' cache-hit metrics must go dark, not fabricate)
-    assert ledger.note_dispatch("verify", object(), "k", "s", 0.1) is None
-    assert ledger.entries()[-1]["event"] == "unknown"
+    # an ahead-of-dispatch compile is the bucket's cold entry; the
+    # dispatch that follows adds the jit-cache entry and records warm
+    # (two compiled before either dispatches: both dispatches are warm)
+    ledger.note_compile("verify", jit, ("xla",), "s16k1", 4.0)
+    ledger.note_compile("verify", jit, ("xla",), "s32k1", 5.0)
+    jit._size = 3
+    assert ledger.note_dispatch("verify", jit, ("xla",), "s16k1", 0.01) == 0
+    jit._size = 4
+    assert ledger.note_dispatch("verify", jit, ("xla",), "s32k1", 0.01) == 0
+    assert [e["event"] for e in ledger.entries()[-4:]] == [
+        "cold", "cold", "warm", "warm"
+    ]
 
 
 def test_compile_ledger_http_endpoint():

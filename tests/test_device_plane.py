@@ -371,6 +371,41 @@ def test_watchdog_timeout_abandons_reaps_and_fails_over():
     assert st["faults"].get("bls:reaped") == 1
 
 
+def test_compile_window_is_not_timed_by_the_watchdog():
+    """Time inside `compile_window` (a cold bucket's trace+compile)
+    extends the watchdog's deadline; execution outside it stays
+    guarded."""
+    g = GuardedExecutor()  # watchdog ON
+
+    def compiles_then_runs(plan):
+        with g.compile_window():
+            time.sleep(0.3)
+        return "verdict"
+
+    out = g.dispatch(
+        "bls", 64, compiles_then_runs,
+        fallbacks=[("ref", lambda: "host-verdict")], timeout_s=0.1,
+    )
+    assert out == "verdict"
+    assert g.stats()["faults"] == {}
+
+    def compiles_then_wedges(plan):
+        with g.compile_window():
+            time.sleep(0.2)
+        time.sleep(0.5)
+        return "late"
+
+    out = g.dispatch(
+        "bls", 64, compiles_then_wedges,
+        fallbacks=[("ref", lambda: "host-verdict")], timeout_s=0.1,
+    )
+    assert out == "host-verdict"
+    assert g.stats()["faults"] == {"bls:timeout": 1}
+    # outside a timed attempt the window is a no-op
+    with g.compile_window():
+        pass
+
+
 def test_per_dispatch_watchdog_opt_out():
     """watchdog=False opts one dispatch out of the watchdog (the
     sharded mesh boundary: multi-minute legitimate cold compiles,

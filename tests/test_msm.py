@@ -149,7 +149,7 @@ def test_device_fixed_base_full_4096_shape():
     ~3 min of CPU-backend XLA even warm (the graph is hardware-scale:
     64 windows x 4096-lane tree folds), so it only runs when asked;
     the committed full_4096 vector is host-verified in tier-1 and the
-    watcher's `kzg` sweep measures this shape on real hardware."""
+    `BENCH_CONFIG=kzg` measures this shape on the chip."""
     if os.environ.get("LIGHTHOUSE_TPU_MSM_FULL") != "1":
         pytest.skip(
             "set LIGHTHOUSE_TPU_MSM_FULL=1 to run the 4096-lane device "

@@ -89,3 +89,21 @@ def test_cli_bn_boots_from_testnet_dir(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "booted network 'minimal'" in out
+
+
+def test_bn_dev_chain_imports_every_slot(capsys):
+    """`bn --slots N`: the dev producer signs each block and the node's
+    chain verifies and imports it — the head moves every slot."""
+    import re
+
+    from lighthouse_tpu.cli import main
+
+    rc = main([
+        "bn", "--validators", "16", "--slots", "2",
+        "--bls-backend", "ref", "--http-port", "0",
+    ])
+    out = capsys.readouterr().out
+    assert rc == 0 and "dev chain complete" in out
+    heads = re.findall(r"^slot (\d+) head=0x([0-9a-f]+)", out, re.M)
+    assert [int(s) for s, _ in heads] == [1, 2]
+    assert heads[0][1] != heads[1][1]

@@ -23,9 +23,7 @@ from scripts.perf_gate import (  # noqa: E402
     EXPECTED_STAGES,
     check_structure,
     check_timing,
-    latest_hardware_line,
     main,
-    stamp_hardware,
 )
 
 
@@ -228,48 +226,6 @@ def test_cli_update_refuses_broken_structure(tmp_path):
     ])
     assert rc == 1
     assert not (tmp_path / "baseline.json").exists()
-
-
-# -------------------------------------------------- hardware stamp plumbing
-
-
-def test_stamp_hardware_round_trip(tmp_path):
-    baseline_path = tmp_path / "baseline.json"
-    baseline_path.write_text(json.dumps(_line()))
-    hw = {
-        "value": 97.0,
-        "stages_p50_ms": {"block_processing": 90.0},
-        "platform": "tpu",
-        "impl": "pallas",
-        "n_sets": 16,
-        "recorded_at": "2026-08-07T00:00:00+00:00",
-        "source": "watcher",
-    }
-    assert stamp_hardware(hw, str(baseline_path))
-    doc = json.loads(baseline_path.read_text())
-    assert doc["hardware"]["value"] == 97.0
-    assert doc["hardware"]["platform"] == "tpu"
-    # the CPU-proxy bands are untouched
-    assert doc["value"] == 9.0
-    # stamping never invents a baseline
-    assert not stamp_hardware(hw, str(tmp_path / "missing.json"))
-
-
-def test_latest_hardware_line_filters(tmp_path):
-    ledger = tmp_path / "m.jsonl"
-    rows = [
-        {"metric": "slotpath_wall_p50_ms", "platform": "cpu",
-         "value": 9.0},
-        {"metric": "verify_signature_sets_throughput",
-         "platform": "tpu", "value": 5425.0},
-        {"metric": "slotpath_wall_p50_ms", "platform": "tpu",
-         "value": 97.0},
-        {"type": "skip", "skipped": "tunnel_down"},
-    ]
-    ledger.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    rec = latest_hardware_line(str(ledger))
-    assert rec is not None and rec["value"] == 97.0
-    assert latest_hardware_line(str(tmp_path / "absent.jsonl")) is None
 
 
 # ------------------------------------------------- the committed baseline

@@ -107,3 +107,35 @@ def test_mxu_redc_override_split_matches():
     assert np.array_equal(np.asarray(ov["tn_hi"]), tf._TN_HI)
     assert np.array_equal(np.asarray(ov["tp_lo"]), tf._TP_LO)
     assert np.array_equal(np.asarray(ov["tp_hi"]), tf._TP_HI)
+
+
+def test_const_overrides_are_per_thread():
+    """A kernel trace installs its constants for its own thread only:
+    programs traced in parallel threads (the verify path's compile-ahead)
+    must never capture each other's tracers."""
+    import threading
+
+    import numpy as np
+
+    installed, release = threading.Event(), threading.Event()
+
+    def tracing_thread():
+        with tf.const_overrides(one="other-thread-tracer"):
+            installed.set()
+            release.wait(5.0)
+
+    t = threading.Thread(target=tracing_thread)
+    t.start()
+    try:
+        assert installed.wait(5.0)
+        col = tf.one_col()  # this thread installed nothing
+        assert not isinstance(col, str)
+        assert np.array_equal(
+            np.asarray(col)[:, 0], np.asarray(tf.fb.ONE_MONT_B)
+        )
+        with tf.const_overrides(one="mine"):
+            assert tf.one_col() == "mine"
+    finally:
+        release.set()
+        t.join(5.0)
+    assert not t.is_alive()
