@@ -100,14 +100,38 @@ def _guard_report(phase, batches_before):
 
 def _verify(sets, seed):
     from lighthouse_tpu.bls import api
-    from lighthouse_tpu.bls.tpu_backend import LAST_HOST_STATS
+    from lighthouse_tpu.common.tracing import TRACER
 
     t0 = time.perf_counter()
     ok = api.verify_signature_sets(
         sets, backend="tpu", seed=seed, consumer="bench"
     )
     wall = time.perf_counter() - t0
-    return ok, wall, dict(LAST_HOST_STATS)
+    root = next(r for r in reversed(TRACER.recent()) if r["name"] == "verify")
+    return ok, wall, _batch_stats(root)
+
+
+def _batch_stats(root):
+    """One batch's host and device phases, from its `verify` span tree."""
+    from lighthouse_tpu.common.tracing import find
+
+    def ms(*names):
+        return 1e3 * sum(
+            s["duration_s"] for n in names for s in find(root, n)
+        )
+
+    marshal = find(root, "verify/marshal")
+    attrs = marshal[0].get("attrs", {}) if marshal else {}
+    return {
+        "shape": attrs.get("shape"),
+        "grouped": attrs.get("layout") == "grouped",
+        "subgroup_ms": ms("verify/subgroup_check"),
+        "host_ms": ms(
+            "verify/subgroup_check", "verify/marshal", "verify/rlc_sample"
+        ),
+        "compile_ms": ms("verify/compile"),
+        "device_ms": ms("verify/device"),
+    }
 
 
 def _batch_phase(name, sets, ref_sample, fillers, seed):

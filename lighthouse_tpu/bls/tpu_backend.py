@@ -34,7 +34,7 @@ from lighthouse_tpu.bls.hash_to_curve import hash_to_g2
 from lighthouse_tpu.common import device_attribution as attribution
 from lighthouse_tpu.common.compile_ledger import LEDGER
 from lighthouse_tpu.common.metrics import REGISTRY
-from lighthouse_tpu.common.tracing import span
+from lighthouse_tpu.common.tracing import span, tag
 from lighthouse_tpu.crypto.ref_curve import G1 as G1_GROUP
 from lighthouse_tpu.crypto.ref_curve import G2 as G2_GROUP
 from lighthouse_tpu.device_plane import GUARD, host_device_scope
@@ -55,11 +55,7 @@ _MSG_CACHE_EVENTS = REGISTRY.counter_vec(
     "hash_to_g2 memo hits vs misses during batch marshalling",
     ("event",),
 )
-_MARSHAL_SECONDS = REGISTRY.histogram_vec(
-    "lighthouse_tpu_marshal_seconds",
-    "host marshalling wall time per phase (points / pack)",
-    ("phase",),
-)
+
 
 def _note_wrapper_event(fn_name: str, hit: bool):
     _JIT_EVENTS.labels(fn_name, "wrapper", "hit" if hit else "trace").inc()
@@ -121,9 +117,6 @@ def _compile_ahead(fn_name: str, jitted, args, shape: str):
 _jitted: dict = {}
 _jitted_indexed: dict = {}
 _JIT_LOCK = threading.Lock()
-
-# host-marshalling telemetry for the last dispatched batch (read by bench)
-LAST_HOST_STATS: dict = {}
 
 # device-dispatch counters (read by tests asserting the <=2-call fallback)
 CALL_COUNTS = {"batch": 0, "individual": 0}
@@ -301,7 +294,8 @@ def _msg_affine(message: bytes):
     hit = _MSG_CACHE.get(message)
     if hit is None:
         _MSG_CACHE_EVENTS.labels("miss").inc()
-        hit = G2_GROUP.to_affine(hash_to_g2(message))
+        with span("verify/marshal/hash_to_g2"):
+            hit = G2_GROUP.to_affine(hash_to_g2(message))
         if len(_MSG_CACHE) >= _MSG_CACHE_MAX:
             _MSG_CACHE.clear()
         _MSG_CACHE[message] = hit
@@ -401,7 +395,6 @@ class _Marshalled:
         "pubkeys",
         "s_bucket",
         "k_bucket",
-        "timings",
         # message-grouped layout (None/False when flat)
         "grouped",
         "group_mask",
@@ -450,7 +443,6 @@ def _marshal(sets, allow_grouped: bool = True) -> _Marshalled:
 def _marshal_grouped(sets, groups) -> _Marshalled:
     """Grid marshal: groups -> (g_bucket, sg_bucket) lanes, messages one
     per group. Padding lanes carry None sigs + all-False key masks."""
-    t0 = time.perf_counter()
     m = _Marshalled()
     G = len(groups)
     g_b = _bucket(G, 1)
@@ -475,7 +467,6 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
 
         sig_aff = batch_to_affine_g2([s.signature.point for s in sets])
         sigs = [None if i is None else sig_aff[i] for i in order]
-    t1 = time.perf_counter()
 
     with span("verify/marshal/pack"):
         m.set_mask = np.array(
@@ -492,48 +483,57 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
             dtype=bool,
         ).reshape(g_b, sg_b, m.k_bucket)
 
-        m.table = _table_for(sets)
-        if m.table is not None:
-            indices = np.full((len(order), m.k_bucket), -1, dtype=np.int32)
-            for lane, i in enumerate(order):
-                if i is None:
-                    continue
-                for k, p in enumerate(sets[i].pubkeys):
-                    indices[lane, k] = p.validator_index
-            m.indices = m.table.gather_indices(indices).reshape(
-                g_b, sg_b, m.k_bucket
-            )
-            m.pubkeys = None
-        else:
-            pk_rows = []
-            for i in order:
-                row = (
-                    []
-                    if i is None
-                    else [G1_GROUP.to_affine(p.point) for p in sets[i].pubkeys]
+        with span(
+            "verify/marshal/pubkeys",
+            slots=sum(len(s.pubkeys) for s in sets),
+        ) as sp:
+            m.table = _table_for(sets)
+            tag(sp, path="packed" if m.table is None else "table")
+            if m.table is not None:
+                indices = np.full(
+                    (len(order), m.k_bucket), -1, dtype=np.int32
                 )
-                pk_rows.append(row + [None] * (m.k_bucket - len(row)))
-            pk_flat = [p for row in pk_rows for p in row]
-            pk_x, pk_y = _pack_g1_affine(pk_flat)
-            m.indices = None
-            m.pubkeys = (
-                np.asarray(pk_x).reshape(g_b, sg_b, m.k_bucket, 1, fb.NB),
-                np.asarray(pk_y).reshape(g_b, sg_b, m.k_bucket, 1, fb.NB),
-            )
+                for lane, i in enumerate(order):
+                    if i is None:
+                        continue
+                    for k, p in enumerate(sets[i].pubkeys):
+                        indices[lane, k] = p.validator_index
+                m.indices = m.table.gather_indices(indices).reshape(
+                    g_b, sg_b, m.k_bucket
+                )
+                m.pubkeys = None
+            else:
+                pk_rows = []
+                for i in order:
+                    row = (
+                        []
+                        if i is None
+                        else [
+                            G1_GROUP.to_affine(p.point)
+                            for p in sets[i].pubkeys
+                        ]
+                    )
+                    pk_rows.append(row + [None] * (m.k_bucket - len(row)))
+                pk_flat = [p for row in pk_rows for p in row]
+                pk_x, pk_y = _pack_g1_affine(pk_flat)
+                m.indices = None
+                m.pubkeys = (
+                    np.asarray(pk_x).reshape(
+                        g_b, sg_b, m.k_bucket, 1, fb.NB
+                    ),
+                    np.asarray(pk_y).reshape(
+                        g_b, sg_b, m.k_bucket, 1, fb.NB
+                    ),
+                )
         m.msgs = _pack_g2_affine(group_msgs)
         m.sigs = tuple(
             np.asarray(c).reshape(g_b, sg_b, 2, fb.NB)
             for c in _pack_g2_affine(sigs)
         )
-    t2 = time.perf_counter()
-    m.timings = {"points_ms": (t1 - t0) * 1e3, "pack_ms": (t2 - t1) * 1e3}
-    _MARSHAL_SECONDS.labels("points").observe(t1 - t0)
-    _MARSHAL_SECONDS.labels("pack").observe(t2 - t1)
     return m
 
 
 def _marshal_flat(sets) -> _Marshalled:
-    t0 = time.perf_counter()
     n_sets = len(sets)
     max_keys = max(len(s.pubkeys) for s in sets)
     m = _Marshalled()
@@ -548,7 +548,6 @@ def _marshal_flat(sets) -> _Marshalled:
         sigs = batch_to_affine_g2([s.signature.point for s in sets])
         msgs += [None] * (m.s_bucket - n_sets)
         sigs += [None] * (m.s_bucket - n_sets)
-    t1 = time.perf_counter()
 
     with span("verify/marshal/pack"):
         m.set_mask = np.array(
@@ -564,56 +563,53 @@ def _marshal_flat(sets) -> _Marshalled:
             dtype=bool,
         )
 
-        m.table = _table_for(sets)
-        if m.table is not None:
-            indices = np.full((m.s_bucket, m.k_bucket), -1, dtype=np.int32)
-            for i, s in enumerate(sets):
-                for k, p in enumerate(s.pubkeys):
-                    indices[i, k] = p.validator_index
-            m.indices = m.table.gather_indices(indices)
-            m.pubkeys = None
-        else:
-            # untagged pubkeys: legacy per-point packing
-            pk_rows = []
-            for s in sets:
-                row = [G1_GROUP.to_affine(p.point) for p in s.pubkeys]
-                pk_rows.append(row + [None] * (m.k_bucket - len(row)))
-            pk_rows += [[None] * m.k_bucket] * (m.s_bucket - n_sets)
-            pk_flat = [p for row in pk_rows for p in row]
-            pk_x, pk_y = _pack_g1_affine(pk_flat)
-            m.indices = None
-            m.pubkeys = (
-                np.asarray(pk_x).reshape(m.s_bucket, m.k_bucket, 1, fb.NB),
-                np.asarray(pk_y).reshape(m.s_bucket, m.k_bucket, 1, fb.NB),
-            )
+        with span(
+            "verify/marshal/pubkeys",
+            slots=sum(len(s.pubkeys) for s in sets),
+        ) as sp:
+            m.table = _table_for(sets)
+            tag(sp, path="packed" if m.table is None else "table")
+            if m.table is not None:
+                indices = np.full(
+                    (m.s_bucket, m.k_bucket), -1, dtype=np.int32
+                )
+                for i, s in enumerate(sets):
+                    for k, p in enumerate(s.pubkeys):
+                        indices[i, k] = p.validator_index
+                m.indices = m.table.gather_indices(indices)
+                m.pubkeys = None
+            else:
+                # untagged pubkeys: legacy per-point packing
+                pk_rows = []
+                for s in sets:
+                    row = [G1_GROUP.to_affine(p.point) for p in s.pubkeys]
+                    pk_rows.append(row + [None] * (m.k_bucket - len(row)))
+                pk_rows += [[None] * m.k_bucket] * (m.s_bucket - n_sets)
+                pk_flat = [p for row in pk_rows for p in row]
+                pk_x, pk_y = _pack_g1_affine(pk_flat)
+                m.indices = None
+                m.pubkeys = (
+                    np.asarray(pk_x).reshape(
+                        m.s_bucket, m.k_bucket, 1, fb.NB
+                    ),
+                    np.asarray(pk_y).reshape(
+                        m.s_bucket, m.k_bucket, 1, fb.NB
+                    ),
+                )
         m.msgs = _pack_g2_affine(msgs)
         m.sigs = _pack_g2_affine(sigs)
-    t2 = time.perf_counter()
-    m.timings = {"points_ms": (t1 - t0) * 1e3, "pack_ms": (t2 - t1) * 1e3}
-    _MARSHAL_SECONDS.labels("points").observe(t1 - t0)
-    _MARSHAL_SECONDS.labels("pack").observe(t2 - t1)
     return m
 
 
-def _record_stats(
-    n_sets, m, t_start, t_subgroup, t_marshal, t_end, compile_s
-):
-    LAST_HOST_STATS.clear()
-    LAST_HOST_STATS.update(
-        {
-            "n_sets": n_sets,
-            "shape": _shape_key(m),
-            "indexed_path": m.table is not None,
-            "grouped": bool(m.grouped),
-            "n_groups": m.n_groups,
-            "subgroup_ms": (t_subgroup - t_start) * 1e3,
-            "points_ms": m.timings["points_ms"],
-            "pack_ms": m.timings["pack_ms"],
-            "host_ms": (t_marshal - t_start) * 1e3,
-            "compile_ms": compile_s * 1e3,
-            "device_ms": (t_end - t_marshal - compile_s) * 1e3,
-        }
-    )
+def _marshal_attrs(m) -> dict:
+    """The `verify/marshal` span's attributes: the layout and program
+    bucket a marshalled batch takes."""
+    return {
+        "layout": "grouped" if m.grouped else "flat",
+        "n_groups": m.n_groups,
+        "indexed": m.table is not None,
+        "shape": _shape_key(m),
+    }
 
 
 def compile_ahead(sets) -> float:
@@ -642,7 +638,6 @@ def _shape_key(m) -> str:
 def verify_signature_sets_tpu(
     sets, seed: int | None = None, consumer: str | None = None
 ) -> bool:
-    t_start = time.perf_counter()
     # host-side policy checks (exact reference semantics)
     with span("verify/subgroup_check", n_sets=len(sets)):
         ok = all(
@@ -651,10 +646,10 @@ def verify_signature_sets_tpu(
         )
     if not ok:
         return False
-    t_subgroup = time.perf_counter()
 
-    with span("verify/marshal", n_sets=len(sets)):
+    with span("verify/marshal", n_sets=len(sets)) as sp:
         m = _marshal(sets)
+        tag(sp, **_marshal_attrs(m))
     with span("verify/rlc_sample"):
         rand_bits = curve.scalars_to_bits(
             _rlc_scalars(m.s_bucket, seed), batch_verify.RAND_BITS
@@ -699,9 +694,6 @@ def verify_signature_sets_tpu(
         lanes=m.s_bucket,
         live=len(sets),
         duration_s=t_end - t_marshal - compile_s,
-    )
-    _record_stats(
-        len(sets), m, t_start, t_subgroup, t_marshal, t_end, compile_s
     )
     return result
 
@@ -892,7 +884,6 @@ def verify_signature_sets_tpu_individual(
     """Per-set verdicts in ONE device call — the batch-failure fallback
     without per-set round trips (attestation batch.rs:115-131 made
     device-shaped; SURVEY §7 hard part 5)."""
-    t_start = time.perf_counter()
     verdicts = [True] * len(sets)
     live = []
     with span("verify/subgroup_check", n_sets=len(sets)):
@@ -903,11 +894,11 @@ def verify_signature_sets_tpu_individual(
                 live.append(i)
     if not live:
         return verdicts
-    t_subgroup = time.perf_counter()
 
     subset = [sets[i] for i in live]
-    with span("verify/marshal", n_sets=len(subset)):
+    with span("verify/marshal", n_sets=len(subset)) as sp:
         m = _marshal(subset, allow_grouped=False)  # per-set pairs needed
+        tag(sp, **_marshal_attrs(m))
     t_marshal = time.perf_counter()
 
     plain_fn, indexed_fn = _get_individual_fns()
@@ -965,8 +956,5 @@ def verify_signature_sets_tpu_individual(
         lanes=m.s_bucket,
         live=len(live),
         duration_s=t_end - t_marshal - compile_s,
-    )
-    _record_stats(
-        len(sets), m, t_start, t_subgroup, t_marshal, t_end, compile_s
     )
     return verdicts

@@ -4,8 +4,14 @@ Role of the reference's `task_executor` timing + tracing-subscriber
 layers, shaped for the TPU pipeline: `with span("verify/miller_loop",
 n_sets=...)` records a nested wall-clock span. Completed ROOT spans land
 in a bounded ring buffer (oldest evicted), exportable as JSONL — one
-span tree per line — for bench attribution (`bench.py` deltas ->
-pipeline stages) and served live over `GET /lighthouse/spans`.
+span tree per line — and served live over `GET /lighthouse/spans`.
+
+Every span also enters a `jax.profiler.TraceAnnotation` of the same
+name once the process has imported JAX, so a profiler trace shows the
+program's stages on the device trace's clock; with no profiler session
+active that is one inactive `TraceMe` per span. The tracer never
+imports JAX itself (the validator client and the signing workers run
+without it).
 
 Leaf spans are additionally mirrored into registry histograms so the
 `/metrics` scrape carries per-stage latency without a second
@@ -17,18 +23,29 @@ instrumentation pass:
 
 Span taxonomy (the instrumented call tree):
 
-  verify                          one verify_signature_sets batch (root)
-    verify/subgroup_check         host signature subgroup policy
-    verify/hash_to_curve          message hashing (ref path, per set)
-    verify/pubkey_aggregation     host G1 aggregation (ref path)
-    verify/to_affine              Jacobian -> affine conversion
-    verify/miller_loop            ref-backend Miller loop
-    verify/final_exp              ref-backend final exponentiation
-    verify/marshal                tpu-backend host marshalling
-      verify/marshal/points       hash memo + simultaneous inversion
-      verify/marshal/pack         mask/limb packing + table indices
-    verify/rlc_sample             RLC scalar sampling
-    verify/device                 device dispatch + verdict force
+  bus/batch                       one verification-bus batch (root;
+                                  attrs batch, trigger, live, submissions)
+    verify/canary                 the known-answer sentinel pair
+                                  (its own subgroup_check, marshal and
+                                  device spans nest under it)
+    verify                        one verify_signature_sets batch (root
+                                  when called outside the bus)
+      verify/subgroup_check       host signature subgroup policy
+      verify/hash_to_curve        message hashing (ref path, per set)
+      verify/pubkey_aggregation   host G1 aggregation (ref path)
+      verify/to_affine            Jacobian -> affine conversion
+      verify/miller_loop          ref-backend Miller loop
+      verify/final_exp            ref-backend final exponentiation
+      verify/marshal              tpu-backend host marshalling (attrs
+                                  layout, n_groups, indexed, shape)
+        verify/marshal/points     hash memo + simultaneous inversion
+          verify/marshal/hash_to_g2   one hash-to-G2 memo miss
+        verify/marshal/pack       mask/limb packing + table indices
+          verify/marshal/pubkeys  pubkey slots: table indices or
+                                  per-key packing (attrs path, slots)
+      verify/compile              a cold bucket's compile-ahead
+      verify/rlc_sample           RLC scalar sampling
+      verify/device               device dispatch + verdict force
                                   (host<->device transfer + kernels)
   import/*                        block-import stages (chain.py)
   trace/*                         JAX trace-time stage attribution for
@@ -36,18 +53,25 @@ Span taxonomy (the instrumented call tree):
                                   once per (re)compile, not per call)
 
 Nesting is tracked per thread; a span closed on one thread never
-corrupts another thread's stack. The tracer is enabled by default with
-a small ring (256 roots); `configure()` (or the `bn --trace-buffer`
-flag) resizes or disables span-tree buffering. Disabling only stops
-tree retention — stage spans still time their bodies and mirror into
-the histograms, so the /metrics scrape never goes dark.
+corrupts another thread's stack. Work handed to a helper thread stays
+in its caller's tree through `current()` and `adopt()`: the guarded
+executor's watchdog worker adopts the dispatching thread's open span,
+so one bus batch is one tree. A child that closes after its adopted
+parent closed (an abandoned worker finishing late) is dropped from the
+tree; its duration still lands in the histograms. The tracer is
+enabled by default with a small ring (256 roots); `configure()` (or the
+`bn --trace-buffer` flag) resizes or disables span-tree buffering.
+Disabling only stops tree retention — stage spans still time their
+bodies and mirror into the histograms, so the /metrics scrape never
+goes dark.
 """
 
 import json
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from lighthouse_tpu.common.metrics import REGISTRY
 
@@ -89,9 +113,24 @@ _SPAN_FALLBACK = REGISTRY.histogram_vec(
 DEFAULT_CAPACITY = 256
 MAX_CHILDREN_PER_SPAN = 512
 
+_NO_ANNOTATION = nullcontext()
+_annotation = None
+
+
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` once the process has imported JAX,
+    else None (looked up again on the next span)."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
 
 class Span:
-    __slots__ = ("name", "wall_start", "duration_s", "attrs", "children")
+    __slots__ = (
+        "name", "wall_start", "duration_s", "attrs", "children", "closed",
+    )
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -99,6 +138,7 @@ class Span:
         self.duration_s = 0.0
         self.attrs = attrs
         self.children: list = []
+        self.closed = False
 
     def to_dict(self) -> dict:
         out = {
@@ -153,44 +193,74 @@ class Tracer:
             st = self._local.stack = []
         return st
 
+    def current(self):
+        """The innermost span open on this thread, or None — what a
+        helper thread `adopt`s to stay in this thread's tree."""
+        st = getattr(self._local, "stack", None)
+        return st[-1] if st else None
+
+    @contextmanager
+    def adopt(self, parent):
+        """Open this thread's spans as children of `parent`, a span
+        another thread holds open (its `current()`); None adopts
+        nothing."""
+        if parent is None:
+            yield
+            return
+        stack = self._stack()
+        stack.append(parent)
+        try:
+            yield
+        finally:
+            stack.pop()
+
     @contextmanager
     def span(self, name: str, **attrs):
-        if not self.enabled:
-            # ring disabled: no tree retention, but the stage-family
-            # histograms keep recording — /metrics must not go dark
-            # because an operator turned off span buffering
+        annotation = _trace_annotation()
+        with annotation(name) if annotation else _NO_ANNOTATION:
+            if not self.enabled:
+                # ring disabled: no tree retention, but the stage-family
+                # histograms keep recording — /metrics must not go dark
+                # because an operator turned off span buffering
+                t0 = time.perf_counter()
+                try:
+                    yield None
+                finally:
+                    self._mirror_duration(
+                        name, time.perf_counter() - t0, leaf=False
+                    )
+                return
+            s = Span(name, attrs)
+            stack = self._stack()
+            stack.append(s)
             t0 = time.perf_counter()
             try:
-                yield None
+                yield s
             finally:
-                self._mirror_duration(
-                    name, time.perf_counter() - t0, leaf=False
-                )
-            return
-        s = Span(name, attrs)
-        stack = self._stack()
-        stack.append(s)
-        t0 = time.perf_counter()
-        try:
-            yield s
-        finally:
-            s.duration_s = time.perf_counter() - t0
-            stack.pop()
-            if stack:
-                parent = stack[-1]
+                s.duration_s = time.perf_counter() - t0
+                stack.pop()
+                self._close(s, stack[-1] if stack else None)
+                self._mirror(s)
+
+    def _close(self, s: Span, parent):
+        # under the lock: an adopted parent is closed on another thread
+        with self._lock:
+            s.closed = True
+            if parent is None:
+                self._roots.append(s)
+                self.completed_roots += 1
+            elif parent.closed:
+                # a late child of a finished tree (abandoned worker):
+                # dropped, its duration still mirrored
+                return
+            elif len(parent.children) < MAX_CHILDREN_PER_SPAN:
+                parent.children.append(s)
+            else:
                 # bound tree size: a 30k-set ref batch would otherwise
                 # pin ~6 Span objects per set in one root
-                if len(parent.children) < MAX_CHILDREN_PER_SPAN:
-                    parent.children.append(s)
-                else:
-                    parent.attrs["children_dropped"] = (
-                        parent.attrs.get("children_dropped", 0) + 1
-                    )
-            else:
-                with self._lock:
-                    self._roots.append(s)
-                    self.completed_roots += 1
-            self._mirror(s)
+                parent.attrs["children_dropped"] = (
+                    parent.attrs.get("children_dropped", 0) + 1
+                )
 
     def _mirror(self, s: Span):
         self._mirror_duration(s.name, s.duration_s, leaf=not s.children)
@@ -247,3 +317,19 @@ def span(name: str, **attrs):
 
 def configure(enabled=None, capacity=None):
     TRACER.configure(enabled=enabled, capacity=capacity)
+
+
+def tag(s, **attrs):
+    """Add attributes to an open span, the value `span(...)` yielded (a
+    no-op on the None a disabled tracer yields)."""
+    if s is not None:
+        s.attrs.update(attrs)
+
+
+def find(tree: dict, name: str) -> list[dict]:
+    """Every span named `name` in an exported tree (a `recent()`
+    entry), depth first."""
+    out = [tree] if tree["name"] == name else []
+    for child in tree["children"]:
+        out += find(child, name)
+    return out

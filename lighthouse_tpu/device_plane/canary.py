@@ -31,6 +31,7 @@ import json
 import threading
 from pathlib import Path
 
+from lighthouse_tpu.common.tracing import span
 from lighthouse_tpu.device_plane.executor import (
     NULL_PLAN,
     CanaryViolation,
@@ -223,23 +224,25 @@ def check_pair(backend: str, plan=NULL_PLAN) -> None:
     canaried batch (`verify_signature_sets_tpu_individual`) — the price
     of catching FALSE-ACCEPTS, which the batch-riding valid sentinel
     cannot see. Sentinel sets stay out of device attribution on both
-    sides (no note_sets, no journal n_sets)."""
-    valid, invalid = bls_sentinels()
-    if backend == "tpu":
-        from lighthouse_tpu.bls.tpu_backend import (
-            verify_signature_sets_tpu_individual,
-        )
-
-        verdicts = [
-            bool(v)
-            for v in verify_signature_sets_tpu_individual(
-                [valid, invalid], consumer="bench"
+    sides (no note_sets, no journal n_sets). Its stages nest under one
+    `verify/canary` span."""
+    with span("verify/canary", backend=backend):
+        valid, invalid = bls_sentinels()
+        if backend == "tpu":
+            from lighthouse_tpu.bls.tpu_backend import (
+                verify_signature_sets_tpu_individual,
             )
-        ]
-    else:
-        from lighthouse_tpu.bls.api import _verify_one_ref
 
-        verdicts = [_verify_one_ref(valid), _verify_one_ref(invalid)]
+            verdicts = [
+                bool(v)
+                for v in verify_signature_sets_tpu_individual(
+                    [valid, invalid], consumer="bench"
+                )
+            ]
+        else:
+            from lighthouse_tpu.bls.api import _verify_one_ref
+
+            verdicts = [_verify_one_ref(valid), _verify_one_ref(invalid)]
     verdicts = list(plan.verdict(verdicts))
     if verdicts != [True, False]:
         raise CanaryViolation(

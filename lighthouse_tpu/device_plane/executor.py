@@ -47,6 +47,7 @@ from contextlib import contextmanager
 
 from lighthouse_tpu.common import slot_budget
 from lighthouse_tpu.common.metrics import REGISTRY
+from lighthouse_tpu.common.tracing import TRACER
 from lighthouse_tpu.device_plane.breaker import CircuitBreaker
 from lighthouse_tpu.device_plane.faults import (
     INJECTOR,
@@ -542,10 +543,15 @@ class GuardedExecutor:
             timeout_s = self.timeout_for(plane, bucket, predicted_s)
         box = {}
         window = {"compile_s": 0.0, "open_at": None}
+        # the attempt's spans stay in the dispatching thread's tree
+        parent = TRACER.current()
 
         def run():
             try:
-                box["result"] = self._run_marked(device_fn, plan, window)
+                with TRACER.adopt(parent):
+                    box["result"] = self._run_marked(
+                        device_fn, plan, window
+                    )
             # lint: allow(except-swallow): watchdog thread trampoline — the exception is re-raised on the caller thread below
             except BaseException as exc:
                 box["error"] = exc

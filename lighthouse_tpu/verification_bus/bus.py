@@ -55,6 +55,7 @@ import time
 from lighthouse_tpu.common import device_attribution as attribution
 from lighthouse_tpu.common import slot_budget
 from lighthouse_tpu.common.metrics import REGISTRY
+from lighthouse_tpu.common.tracing import span
 from lighthouse_tpu.verification_bus.wall_model import PredictedWallModel
 
 _SUBMITTED = REGISTRY.counter_vec(
@@ -743,7 +744,11 @@ class VerificationBus:
         exc = None
         record = None
         try:
-            ok, record = self._shared_verify(subs, backend)
+            with span(
+                "bus/batch", batch=batch_id, trigger=trigger,
+                live=total_live, submissions=len(subs),
+            ):
+                ok, record = self._shared_verify(subs, backend)
         except Exception as e:
             ok = False
             exc = e
@@ -783,7 +788,11 @@ class VerificationBus:
             sub_exc = None
             sub_record = None
             try:
-                ok_i, sub_record = self._shared_verify([s], backend)
+                with span(
+                    "bus/batch", batch=sub_id, trigger="fallback",
+                    live=len(s.sets), submissions=1,
+                ):
+                    ok_i, sub_record = self._shared_verify([s], backend)
             except Exception as e:
                 ok_i = False
                 sub_exc = e

@@ -10,6 +10,7 @@ import pytest
 
 from lighthouse_tpu import bls
 from lighthouse_tpu.bls.point_serde import DecodeError, g1_compress, g1_decompress
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.crypto.constants import R
 from lighthouse_tpu.crypto.ref_curve import G1 as G1_GROUP
 
@@ -236,6 +237,14 @@ def test_native_subgroup_checks_match_python():
         assert g2decomp.g2_in_subgroup(pt[0], pt[1]) is False
 
 
+def _last_marshal():
+    """Attributes of the newest `verify/marshal` span the tracer holds."""
+    return [
+        m for r in tracing.TRACER.recent()
+        for m in tracing.find(r, "verify/marshal")
+    ][-1]["attrs"]
+
+
 def test_tpu_backend_grouped_dispatch():
     """Sets sharing messages route through the message-grouped device
     path (G+1 pairs): verdicts match the ref backend, forgery fails the
@@ -253,8 +262,8 @@ def test_tpu_backend_grouped_dispatch():
     ]
 
     assert bls.verify_signature_sets(sets, backend="tpu", seed=3)
-    assert tpu_backend.LAST_HOST_STATS["grouped"] is True
-    assert tpu_backend.LAST_HOST_STATS["n_groups"] == 2
+    assert _last_marshal()["layout"] == "grouped"
+    assert _last_marshal()["n_groups"] == 2
 
     # forged member -> batch False; per-set fallback isolates it
     bad = list(sets)
@@ -262,13 +271,13 @@ def test_tpu_backend_grouped_dispatch():
     assert not bls.verify_signature_sets(bad, backend="tpu", seed=3)
     verdicts = tpu_backend.verify_signature_sets_tpu_individual(bad)
     assert verdicts == [True] * 5 + [False] + [True] * 2
-    assert tpu_backend.LAST_HOST_STATS["grouped"] is False
+    assert _last_marshal()["layout"] == "flat"
 
     # kill switch: flat layout, same verdict
     os.environ["LIGHTHOUSE_TPU_GROUPED"] = "0"
     try:
         assert bls.verify_signature_sets(sets, backend="tpu", seed=3)
-        assert tpu_backend.LAST_HOST_STATS["grouped"] is False
+        assert _last_marshal()["layout"] == "flat"
     finally:
         del os.environ["LIGHTHOUSE_TPU_GROUPED"]
 
@@ -279,4 +288,4 @@ def test_tpu_backend_grouped_dispatch():
         for i, p in enumerate(pairs)
     ]
     assert bls.verify_signature_sets(distinct, backend="tpu", seed=3)
-    assert tpu_backend.LAST_HOST_STATS["grouped"] is False
+    assert _last_marshal()["layout"] == "flat"

@@ -11,6 +11,7 @@ import pytest
 
 from lighthouse_tpu import bls
 from lighthouse_tpu.bls import tpu_backend as tb
+from lighthouse_tpu.common import tracing
 from lighthouse_tpu.state_processing.pubkey_cache import PubkeyCache
 
 
@@ -22,6 +23,14 @@ class _V:
 class _State:
     def __init__(self, pk_bytes_list):
         self.validators = [_V(b) for b in pk_bytes_list]
+
+
+def _last_marshal():
+    """Attributes of the newest `verify/marshal` span the tracer holds."""
+    return [
+        m for r in tracing.TRACER.recent()
+        for m in tracing.find(r, "verify/marshal")
+    ][-1]["attrs"]
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +52,7 @@ def test_indexed_gather_path_verifies(cache_and_keys):
         for i, kp in enumerate(kps)
     ]
     assert bls.verify_signature_sets(sets, backend="tpu", seed=1)
-    assert tb.LAST_HOST_STATS["indexed_path"]
+    assert _last_marshal()["indexed"]
 
     # one forged signature breaks the whole batch
     bad = bls.SignatureSet(kps[0].sk.sign(b"other"), [cache.get(1)], msg)
@@ -58,7 +67,7 @@ def test_untagged_pubkeys_use_legacy_packing(cache_and_keys):
     raw_pk = bls.PublicKey.from_bytes(kps[0].pk.to_bytes())
     legacy = [bls.SignatureSet(kps[0].sk.sign(msg), [raw_pk], msg)]
     assert bls.verify_signature_sets(legacy, backend="tpu", seed=1)
-    assert not tb.LAST_HOST_STATS["indexed_path"]
+    assert not _last_marshal()["indexed"]
 
 
 def test_multi_key_aggregate_through_table(cache_and_keys):
@@ -67,7 +76,7 @@ def test_multi_key_aggregate_through_table(cache_and_keys):
     agg = bls.aggregate_signatures([kp.sk.sign(msg) for kp in kps[:3]])
     aset = bls.SignatureSet(agg, [cache.get(i) for i in range(3)], msg)
     assert bls.verify_signature_sets([aset], backend="tpu", seed=2)
-    assert tb.LAST_HOST_STATS["indexed_path"]
+    assert _last_marshal()["indexed"]
 
 
 def test_table_growth_after_new_validators(cache_and_keys):
@@ -83,7 +92,7 @@ def test_table_growth_after_new_validators(cache_and_keys):
     msg = b"\x44" * 32
     sset = bls.SignatureSet(extra.sk.sign(msg), [cache.get(before)], msg)
     assert bls.verify_signature_sets([sset], backend="tpu", seed=3)
-    assert tb.LAST_HOST_STATS["indexed_path"]
+    assert _last_marshal()["indexed"]
 
 
 def test_one_bad_sig_fallback_two_device_calls(cache_and_keys):
