@@ -17,7 +17,9 @@ Phases, all in this one process (one process holds the chip):
   sync       one 512-key sync-committee aggregate (BASELINE config 2).
   bn         `lighthouse_tpu bn --network mainnet --bls-backend tpu
              --validators 8192 --slots 4`: every block imports and the
-             head advances every slot.
+             head advances every slot, and the node's batches gather
+             pubkeys from the table its chain built at start-up. The
+             phase reports that build and the first bus batch's costs.
 
 After every phase the guard must show no failover, device fault, open
 breaker or abandoned dispatch, and the phase must have recorded at least
@@ -44,6 +46,9 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+
+NODE_VALIDATORS = 8192  # the bn phase's registry
 
 
 class SmokeFailure(Exception):
@@ -184,10 +189,89 @@ def _batch_phase(name, sets, ref_sample, fillers, seed):
     }
 
 
+def _node_batch(sets, validators):
+    """`sets` in the shape of the node phase's canaried bus batches: each
+    key tagged by a PubkeyCache of `validators` keys whose device table
+    is built (the node's table shape), then the canary's valid sentinel,
+    which rides the batch as an overflow row."""
+    from lighthouse_tpu import bls
+    from lighthouse_tpu.device_plane import canary
+    from lighthouse_tpu.state_processing.pubkey_cache import PubkeyCache
+
+    points = [pk.point for s in sets for pk in s.pubkeys]
+    cache = PubkeyCache()
+    for v in range(validators):
+        pk = bls.PublicKey(points[v % len(points)])
+        pk.validator_index = v
+        pk.cache = cache
+        cache._by_index.append(pk)
+    cache.device_table()
+    tagged = []
+    for s in sets:
+        keys = cache._by_index[: len(s.pubkeys)]
+        tagged.append(bls.SignatureSet(s.signature, keys, s.message))
+    return tagged + [canary.bls_sentinels()[0]]
+
+
+def _pubkey_slots():
+    """{path: live pubkey slots} marshalled so far."""
+    from lighthouse_tpu.bls import tpu_backend
+
+    return {
+        path: int(tpu_backend._PUBKEY_SLOTS.labels(path).value)
+        for path in ("table", "overflow", "packed")
+    }
+
+
+def _node_costs(t_phase):
+    """The node's start-up table build and its first canaried bus batch,
+    from the span trees opened since `t_phase` (wall clock)."""
+    from lighthouse_tpu.common.tracing import TRACER, find
+
+    roots = [r for r in TRACER.recent() if r["wall_start"] >= t_phase]
+    builds = [r for r in roots if r["name"] == "chain/pubkey_table"]
+    batches = sorted(
+        (b for r in roots for b in find(r, "bus/batch")),
+        key=lambda b: b["wall_start"],
+    )
+    out = {
+        "pubkey_table_build_s": [b["duration_s"] for b in builds],
+        "pubkey_table_keys": [b["attrs"].get("keys") for b in builds],
+        "bus_batches": len(batches),
+    }
+    if batches:
+        first = batches[0]
+
+        def ms(name):
+            return 1e3 * sum(s["duration_s"] for s in find(first, name))
+
+        out["first_bus_batch"] = {
+            "wall_ms": 1e3 * first["duration_s"],
+            "shapes": [
+                m["attrs"].get("shape") for m in find(first, "verify/marshal")
+            ],
+            "pubkey_paths": [
+                p["attrs"].get("path")
+                for p in find(first, "verify/marshal/pubkeys")
+            ],
+            "marshal_ms": ms("verify/marshal"),
+            "pubkeys_ms": ms("verify/marshal/pubkeys"),
+            "compile_ms": ms("verify/compile"),
+            "canary_ms": ms("verify/canary"),
+            "device_ms": ms("verify/device"),
+        }
+    return out
+
+
 def _bn_phase(validators, slots):
     from lighthouse_tpu import cli
+    from lighthouse_tpu.common import tracing
 
+    # room for every span tree of the phase, so its first batch stays
+    tracing.configure(capacity=8192)
     before = _device_batches()
+    slots_before = _pubkey_slots()
+    t_phase = time.time()
     out = io.StringIO()
     t0 = time.perf_counter()
     with contextlib.redirect_stdout(out):
@@ -209,12 +293,21 @@ def _bn_phase(validators, slots):
         len({h for _, h in heads}) == slots,
         f"bn: head did not advance every slot {heads}",
     )
+    slots_after = _pubkey_slots()
+    _check(
+        slots_after["table"] > slots_before["table"],
+        "bn: no pubkey slot gathered from the chain's table",
+    )
     return {
         "phase": "bn",
         "validators": validators,
         "slots": slots,
         "heads": [h for _, h in heads],
         "wall_s": wall,
+        "pubkey_slots": {
+            k: slots_after[k] - slots_before[k] for k in slots_after
+        },
+        **_node_costs(t_phase),
         "guard": _guard_report("bn", before),
     }
 
@@ -255,16 +348,24 @@ def main(argv=None):
     # verify program is minutes of Mosaic compile, and concurrent
     # compiles finish several times sooner than back to back). Besides
     # the three API shapes: the buckets of the node phase's canaried bus
-    # batches at 8192 validators — at most 4 single-key sets, and 5-8
-    # sets of at most 128 keys (a bucket not warmed here is compiled on
-    # first use, outside the watchdog, just later)
+    # batches at 8192 validators — chain-tagged keys gathered from the
+    # node's pubkey table plus the canary's untagged sentinel, at most 4
+    # single-key sets, and 5-8 sets of at most 128 keys (a bucket not
+    # warmed here is compiled on first use, outside the watchdog, just
+    # later)
     buckets = {
         "slot": slot,
         "distinct": distinct,
         "sync": sync,
-        "node_s4k1": td.make_api_signature_sets(3, 1, seed=args.seed + 3),
-        "node_s8k128": td.make_api_signature_sets(
-            5, 1, keys_per_set=128, seed=args.seed + 4
+        "node_s4k1": _node_batch(
+            td.make_api_signature_sets(3, 1, seed=args.seed + 3),
+            NODE_VALIDATORS,
+        ),
+        "node_s8k128": _node_batch(
+            td.make_api_signature_sets(
+                5, 1, keys_per_set=128, seed=args.seed + 4
+            ),
+            NODE_VALIDATORS,
         ),
     }
     t0 = time.perf_counter()
@@ -279,7 +380,7 @@ def main(argv=None):
         "wall_s": time.perf_counter() - t0,
         "compile_s": compile_s,
     }), flush=True)
-    print(json.dumps(_bn_phase(8192, 4)), flush=True)
+    print(json.dumps(_bn_phase(NODE_VALIDATORS, 4)), flush=True)
     fillers = distinct[1:]
     for phase in (
         ("distinct", distinct, [0, 1023], fillers),

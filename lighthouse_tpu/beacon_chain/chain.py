@@ -174,6 +174,11 @@ class BeaconChain:
         self.store.journal = self.journal
         self.pubkey_cache = PubkeyCache()
         self.pubkey_cache.import_new(genesis_state)
+        if backend == "tpu":
+            # the HBM pubkey table every signature batch gathers from:
+            # built and uploaded here, once, as no batch ever builds it
+            with span("chain/pubkey_table", keys=len(self.pubkey_cache)):
+                self.pubkey_cache.device_table()
         self.slot_clock = slot_clock
 
         genesis_root = self._header_root(genesis_state)

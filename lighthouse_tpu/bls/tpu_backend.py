@@ -10,7 +10,13 @@ TPU-native replacement for the reference's dynamic per-set heap vectors
 Hot-path design (SURVEY §7 hard part 4, validator_pubkey_cache.rs:9-24):
   * pubkeys tagged by the chain's PubkeyCache ship as int32 table indices;
     the device gathers affine Montgomery limbs from the HBM-resident
-    DevicePubkeyTable — zero per-pubkey Python work per batch.
+    DevicePubkeyTable — zero per-pubkey Python work per batch. The few
+    untagged keys of such a batch (the canary sentinel, withdrawal keys)
+    are packed on the host as a small block of overflow rows, indexed
+    past the table's capacity; only a batch with no tagged key at all
+    packs every slot. The chain builds the table when it starts on this
+    backend; a marshal never builds it, and packs every slot while the
+    cache has no complete table.
   * message hash_to_g2 results are memoized — a slot's 30k attestation
     sets share ~committee-count distinct messages, so the cache collapses
     the per-set cost to a dict hit.
@@ -30,6 +36,7 @@ import numpy as np
 
 import jax
 
+from lighthouse_tpu.bls.device_pubkey_table import limb_rows, tagging_cache
 from lighthouse_tpu.bls.hash_to_curve import hash_to_g2
 from lighthouse_tpu.common import device_attribution as attribution
 from lighthouse_tpu.common.compile_ledger import LEDGER
@@ -54,6 +61,12 @@ _MSG_CACHE_EVENTS = REGISTRY.counter_vec(
     "lighthouse_tpu_msg_cache_events_total",
     "hash_to_g2 memo hits vs misses during batch marshalling",
     ("event",),
+)
+_PUBKEY_SLOTS = REGISTRY.counter_vec(
+    "lighthouse_tpu_pubkey_slots_total",
+    "live pubkey slots marshalled, by where their limbs came from: the"
+    " HBM table, the batch's overflow rows, or packed key by key",
+    ("path",),
 )
 
 
@@ -199,17 +212,35 @@ def _get_fn():
     return fn
 
 
-def _indexed_verify(
-    use_pallas, msgs, sigs, table_x, table_y, indices, key_mask,
-    rand_bits, set_mask,
-):
-    """Gather pubkey limb rows by validator index on device, then verify."""
+def _gather_pubkeys(table_x, table_y, over_x, over_y, indices):
+    """Pubkey limb rows for an index array: index < capacity reads the
+    table, index capacity + j the batch's overflow row j. Both gathers
+    run on clipped indices and the select picks one, so the table is
+    never copied."""
     import jax.numpy as jnp
 
-    pk_x = jnp.take(table_x, indices, axis=0)  # (S, K, 1, NB)
-    pk_y = jnp.take(table_y, indices, axis=0)
+    cap = table_x.shape[0]
+    t_idx = jnp.clip(indices, 0, cap - 1)
+    o_idx = jnp.clip(indices - cap, 0, over_x.shape[0] - 1)
+    in_table = (indices < cap)[..., None, None]
+    return tuple(
+        jnp.where(
+            in_table,
+            jnp.take(table, t_idx, axis=0),
+            jnp.take(over, o_idx, axis=0),
+        )
+        for table, over in ((table_x, over_x), (table_y, over_y))
+    )
+
+
+def _indexed_verify(
+    use_pallas, msgs, sigs, table_x, table_y, over_x, over_y, indices,
+    key_mask, rand_bits, set_mask,
+):
+    """Gather pubkey limb rows by table index on device, then verify."""
+    pks = _gather_pubkeys(table_x, table_y, over_x, over_y, indices)
     return _verify_impl(use_pallas)(
-        msgs, sigs, (pk_x, pk_y), key_mask, rand_bits, set_mask
+        msgs, sigs, pks, key_mask, rand_bits, set_mask
     )
 
 
@@ -227,16 +258,12 @@ def _grouped_impl(use_pallas: bool):
 
 
 def _grouped_indexed_verify(
-    use_pallas, msgs, sigs, table_x, table_y, indices, key_mask,
-    rand_bits, set_mask, group_mask,
+    use_pallas, msgs, sigs, table_x, table_y, over_x, over_y, indices,
+    key_mask, rand_bits, set_mask, group_mask,
 ):
-    import jax.numpy as jnp
-
-    pk_x = jnp.take(table_x, indices, axis=0)  # (G, Sg, K, 1, NB)
-    pk_y = jnp.take(table_y, indices, axis=0)
+    pks = _gather_pubkeys(table_x, table_y, over_x, over_y, indices)
     return _grouped_impl(use_pallas)(
-        msgs, sigs, (pk_x, pk_y), key_mask, rand_bits, set_mask,
-        group_mask,
+        msgs, sigs, pks, key_mask, rand_bits, set_mask, group_mask,
     )
 
 
@@ -363,23 +390,18 @@ def _rlc_scalars(n: int, seed):
 
 
 def _table_for(sets):
-    """The shared DevicePubkeyTable when EVERY pubkey in every set is
-    tagged by one PubkeyCache covering its index; else None."""
-    cache = None
+    """(cache, DevicePubkeyTable) of the PubkeyCache that tags the
+    batch's first tagged pubkey, when that cache's table is built and
+    holds every key it does; else None (no tagged key, or no table yet:
+    a marshal never builds one). Keys that cache does not tag become
+    the batch's overflow rows."""
     for s in sets:
         for p in s.pubkeys:
-            c = getattr(p, "cache", None)
-            idx = getattr(p, "validator_index", None)
-            if c is None or idx is None:
-                return None
-            if cache is None:
-                cache = c
-            elif c is not cache:
-                return None
-    if cache is None:
-        return None
-    table = cache.device_table()
-    return table if table.count == len(cache) else None
+            cache = tagging_cache(p)
+            if cache is not None:
+                table = cache.ready_table()
+                return None if table is None else (cache, table)
+    return None
 
 
 class _Marshalled:
@@ -390,8 +412,11 @@ class _Marshalled:
         "sigs",
         "key_mask",
         "set_mask",
+        # table path: the table's device (x, y) rows, the index array
+        # and the (E, 1, NB) overflow rows; packed path: pubkeys
         "table",
         "indices",
+        "overflow",
         "pubkeys",
         "s_bucket",
         "k_bucket",
@@ -483,48 +508,7 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
             dtype=bool,
         ).reshape(g_b, sg_b, m.k_bucket)
 
-        with span(
-            "verify/marshal/pubkeys",
-            slots=sum(len(s.pubkeys) for s in sets),
-        ) as sp:
-            m.table = _table_for(sets)
-            tag(sp, path="packed" if m.table is None else "table")
-            if m.table is not None:
-                indices = np.full(
-                    (len(order), m.k_bucket), -1, dtype=np.int32
-                )
-                for lane, i in enumerate(order):
-                    if i is None:
-                        continue
-                    for k, p in enumerate(sets[i].pubkeys):
-                        indices[lane, k] = p.validator_index
-                m.indices = m.table.gather_indices(indices).reshape(
-                    g_b, sg_b, m.k_bucket
-                )
-                m.pubkeys = None
-            else:
-                pk_rows = []
-                for i in order:
-                    row = (
-                        []
-                        if i is None
-                        else [
-                            G1_GROUP.to_affine(p.point)
-                            for p in sets[i].pubkeys
-                        ]
-                    )
-                    pk_rows.append(row + [None] * (m.k_bucket - len(row)))
-                pk_flat = [p for row in pk_rows for p in row]
-                pk_x, pk_y = _pack_g1_affine(pk_flat)
-                m.indices = None
-                m.pubkeys = (
-                    np.asarray(pk_x).reshape(
-                        g_b, sg_b, m.k_bucket, 1, fb.NB
-                    ),
-                    np.asarray(pk_y).reshape(
-                        g_b, sg_b, m.k_bucket, 1, fb.NB
-                    ),
-                )
+        _marshal_pubkeys(m, sets, order, (g_b, sg_b))
         m.msgs = _pack_g2_affine(group_msgs)
         m.sigs = tuple(
             np.asarray(c).reshape(g_b, sg_b, 2, fb.NB)
@@ -563,42 +547,52 @@ def _marshal_flat(sets) -> _Marshalled:
             dtype=bool,
         )
 
-        with span(
-            "verify/marshal/pubkeys",
-            slots=sum(len(s.pubkeys) for s in sets),
-        ) as sp:
-            m.table = _table_for(sets)
-            tag(sp, path="packed" if m.table is None else "table")
-            if m.table is not None:
-                indices = np.full(
-                    (m.s_bucket, m.k_bucket), -1, dtype=np.int32
-                )
-                for i, s in enumerate(sets):
-                    for k, p in enumerate(s.pubkeys):
-                        indices[i, k] = p.validator_index
-                m.indices = m.table.gather_indices(indices)
-                m.pubkeys = None
-            else:
-                # untagged pubkeys: legacy per-point packing
-                pk_rows = []
-                for s in sets:
-                    row = [G1_GROUP.to_affine(p.point) for p in s.pubkeys]
-                    pk_rows.append(row + [None] * (m.k_bucket - len(row)))
-                pk_rows += [[None] * m.k_bucket] * (m.s_bucket - n_sets)
-                pk_flat = [p for row in pk_rows for p in row]
-                pk_x, pk_y = _pack_g1_affine(pk_flat)
-                m.indices = None
-                m.pubkeys = (
-                    np.asarray(pk_x).reshape(
-                        m.s_bucket, m.k_bucket, 1, fb.NB
-                    ),
-                    np.asarray(pk_y).reshape(
-                        m.s_bucket, m.k_bucket, 1, fb.NB
-                    ),
-                )
+        order = list(range(n_sets)) + [None] * (m.s_bucket - n_sets)
+        _marshal_pubkeys(m, sets, order, (m.s_bucket,))
         m.msgs = _pack_g2_affine(msgs)
         m.sigs = _pack_g2_affine(sigs)
     return m
+
+
+def _marshal_pubkeys(m, sets, order, lanes_shape):
+    """Fill a marshal's pubkey slots for its lanes (`order`: the set
+    index of each lane, None for padding; `lanes_shape` the layout's
+    lane grid). A batch with a tagged key takes the table path: indices
+    into the HBM table, the untagged keys as overflow rows in a
+    power-of-two bucket of at least 8, so a batch with up to 8 of them
+    (the canary sentinel) reaches one program. A batch with no tagged
+    key packs every slot on the host."""
+    live = sum(len(s.pubkeys) for s in sets)
+    with span("verify/marshal/pubkeys", slots=live) as sp:
+        found = _table_for(sets)
+        if found is None:
+            pk_flat = []
+            for i in order:
+                row = [] if i is None else [
+                    G1_GROUP.to_affine(p.point) for p in sets[i].pubkeys
+                ]
+                pk_flat += row + [None] * (m.k_bucket - len(row))
+            shape = lanes_shape + (m.k_bucket, 1, fb.NB)
+            m.table = m.indices = m.overflow = None
+            m.pubkeys = tuple(
+                np.asarray(c).reshape(shape)
+                for c in _pack_g1_affine(pk_flat)
+            )
+            _PUBKEY_SLOTS.labels("packed").inc(live)
+            tag(sp, path="packed", overflow=0)
+            return
+        cache, table = found
+        m.table, indices, over = table.index_lanes(
+            [None if i is None else sets[i].pubkeys for i in order],
+            m.k_bucket,
+            cache,
+        )
+        m.indices = indices.reshape(lanes_shape + (m.k_bucket,))
+        m.overflow = limb_rows(over, _bucket(len(over), 8))
+        m.pubkeys = None
+        _PUBKEY_SLOTS.labels("table").inc(live - len(over))
+        _PUBKEY_SLOTS.labels("overflow").inc(len(over))
+        tag(sp, path="table", overflow=len(over))
 
 
 def _marshal_attrs(m) -> dict:
@@ -628,11 +622,16 @@ def compile_ahead(sets) -> float:
 
 def _shape_key(m) -> str:
     """Shape-bucket string for the compile ledger: the (set, key)
-    bucket class this marshal compiled/hit."""
+    bucket class this marshal compiled/hit, and on the table path the
+    overflow-row bucket."""
     if m.grouped:
         g_b, sg_b = m.set_mask.shape
-        return f"g{g_b}x{sg_b}k{m.k_bucket}"
-    return f"s{m.s_bucket}k{m.k_bucket}"
+        key = f"g{g_b}x{sg_b}k{m.k_bucket}"
+    else:
+        key = f"s{m.s_bucket}k{m.k_bucket}"
+    if m.table is not None:
+        key += f"e{m.overflow[0].shape[0]}"
+    return key
 
 
 def verify_signature_sets_tpu(
@@ -714,20 +713,18 @@ def _program(m, rand_bits):
         )
         plain, indexed = _get_grouped_fns()
         if m.table is not None:
-            tx, ty = m.table.rows()
             return "verify_grouped_indexed", indexed, (
-                m.msgs, m.sigs, tx, ty, m.indices, m.key_mask,
-                rand_bits, m.set_mask, m.group_mask,
+                m.msgs, m.sigs, *m.table, *m.overflow, m.indices,
+                m.key_mask, rand_bits, m.set_mask, m.group_mask,
             )
         return "verify_grouped", plain, (
             m.msgs, m.sigs, m.pubkeys, m.key_mask, rand_bits,
             m.set_mask, m.group_mask,
         )
     if m.table is not None:
-        tx, ty = m.table.rows()
         return "verify_indexed", _get_indexed_fn(), (
-            m.msgs, m.sigs, tx, ty, m.indices, m.key_mask, rand_bits,
-            m.set_mask,
+            m.msgs, m.sigs, *m.table, *m.overflow, m.indices,
+            m.key_mask, rand_bits, m.set_mask,
         )
     return "verify", _get_fn(), (
         m.msgs, m.sigs, m.pubkeys, m.key_mask, rand_bits, m.set_mask
@@ -852,14 +849,12 @@ def verify_signature_set_batches_tpu(
 
 
 def _indexed_individual(
-    msgs, sigs, table_x, table_y, indices, key_mask, set_mask
+    msgs, sigs, table_x, table_y, over_x, over_y, indices, key_mask,
+    set_mask,
 ):
-    import jax.numpy as jnp
-
-    pk_x = jnp.take(table_x, indices, axis=0)
-    pk_y = jnp.take(table_y, indices, axis=0)
+    pks = _gather_pubkeys(table_x, table_y, over_x, over_y, indices)
     return batch_verify.verify_signature_sets_individual(
-        msgs, sigs, (pk_x, pk_y), key_mask, set_mask
+        msgs, sigs, pks, key_mask, set_mask
     )
 
 
@@ -905,9 +900,9 @@ def verify_signature_sets_tpu_individual(
     CALL_COUNTS["individual"] += 1
     shape = _shape_key(m)
     if m.table is not None:
-        tx, ty = m.table.rows()
         name, fn, args = "verify_individual_indexed", indexed_fn, (
-            m.msgs, m.sigs, tx, ty, m.indices, m.key_mask, m.set_mask
+            m.msgs, m.sigs, *m.table, *m.overflow, m.indices,
+            m.key_mask, m.set_mask,
         )
     else:
         name, fn, args = "verify_individual", plain_fn, (

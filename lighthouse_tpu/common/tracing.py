@@ -41,12 +41,15 @@ Span taxonomy (the instrumented call tree):
         verify/marshal/points     hash memo + simultaneous inversion
           verify/marshal/hash_to_g2   one hash-to-G2 memo miss
         verify/marshal/pack       mask/limb packing + table indices
-          verify/marshal/pubkeys  pubkey slots: table indices or
-                                  per-key packing (attrs path, slots)
+          verify/marshal/pubkeys  pubkey slots: table indices plus
+                                  overflow rows, or per-key packing
+                                  (attrs path, slots, overflow)
       verify/compile              a cold bucket's compile-ahead
       verify/rlc_sample           RLC scalar sampling
       verify/device               device dispatch + verdict force
                                   (host<->device transfer + kernels)
+  chain/pubkey_table              the HBM pubkey table's build at a
+                                  TPU chain's start-up (root; attrs keys)
   import/*                        block-import stages (chain.py)
   trace/*                         JAX trace-time stage attribution for
                                   the jitted device graphs (recorded

@@ -15,7 +15,7 @@ class PubkeyCache:
     def __init__(self):
         self._by_index: list[bls.PublicKey] = []
         self._by_bytes: dict[bytes, int] = {}
-        self._device_table = None  # built lazily; appended on import_new
+        self._device_table = None  # built by device_table(), then synced
 
     def import_new(self, state):
         """Pick up any validators appended since the last import."""
@@ -31,13 +31,26 @@ class PubkeyCache:
             self._device_table.append(self._by_index[start:])
 
     def device_table(self):
-        """The device-resident limb table, synced to the cache."""
+        """The device-resident limb table, synced to the cache. The first
+        call builds it from every cached key, a start-up cost (the chain
+        pays it when it starts on the TPU backend); import_new appends to
+        it from then on."""
         from lighthouse_tpu.bls.device_pubkey_table import DevicePubkeyTable
 
         if self._device_table is None:
-            self._device_table = DevicePubkeyTable()
-            self._device_table.append(self._by_index)
+            table = DevicePubkeyTable()
+            table.append(self._by_index)
+            table.rows()
+            self._device_table = table
         return self._device_table
+
+    def ready_table(self):
+        """The device table when it is built and holds every cached key,
+        else None. Never builds it: a signature batch only reads it."""
+        table = self._device_table
+        if table is None or table.count != len(self._by_index):
+            return None
+        return table
 
     def get(self, index: int) -> bls.PublicKey:
         return self._by_index[index]

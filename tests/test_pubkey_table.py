@@ -11,7 +11,11 @@ import pytest
 
 from lighthouse_tpu import bls
 from lighthouse_tpu.bls import tpu_backend as tb
+from lighthouse_tpu.bls.device_pubkey_table import DevicePubkeyTable
 from lighthouse_tpu.common import tracing
+from lighthouse_tpu.common.metrics import REGISTRY
+from lighthouse_tpu.device_plane import canary
+from lighthouse_tpu.ops import fieldb as fb
 from lighthouse_tpu.state_processing.pubkey_cache import PubkeyCache
 
 
@@ -25,12 +29,25 @@ class _State:
         self.validators = [_V(b) for b in pk_bytes_list]
 
 
-def _last_marshal():
-    """Attributes of the newest `verify/marshal` span the tracer holds."""
+def _last_marshal(name="verify/marshal"):
+    """Attributes of the newest span `name` the tracer holds."""
     return [
         m for r in tracing.TRACER.recent()
-        for m in tracing.find(r, "verify/marshal")
+        for m in tracing.find(r, name)
     ][-1]["attrs"]
+
+
+def _twin(kp):
+    """`kp`'s public key decoded afresh: the same point, untagged."""
+    return bls.PublicKey.from_bytes(kp.pk.to_bytes())
+
+
+def _slots():
+    fam = REGISTRY.get("lighthouse_tpu_pubkey_slots_total")
+    return {
+        path: fam.labels(path).value
+        for path in ("table", "overflow", "packed")
+    }
 
 
 @pytest.fixture(scope="module")
@@ -41,6 +58,7 @@ def cache_and_keys():
     ]
     cache = PubkeyCache()
     cache.import_new(_State([kp.pk.to_bytes() for kp in kps]))
+    cache.device_table()  # as a chain on the TPU backend does at start-up
     return cache, kps
 
 
@@ -53,6 +71,17 @@ def test_indexed_gather_path_verifies(cache_and_keys):
     ]
     assert bls.verify_signature_sets(sets, backend="tpu", seed=1)
     assert _last_marshal()["indexed"]
+
+    # an untagged key rides the same program as an overflow row
+    twin = list(sets)
+    twin[3] = bls.SignatureSet(sets[3].signature, [_twin(kps[3])], msg)
+    assert bls.verify_signature_sets(twin, backend="tpu", seed=1)
+    assert _last_marshal()["indexed"]
+    assert _last_marshal("verify/marshal/pubkeys")["overflow"] == 1
+    # ... and is the key it claims to be: another key's twin fails
+    twin[3] = bls.SignatureSet(sets[3].signature, [_twin(kps[4])], msg)
+    assert not bls.verify_signature_sets(twin, backend="tpu", seed=1)
+    assert _last_marshal("verify/marshal/pubkeys")["overflow"] == 1
 
     # one forged signature breaks the whole batch
     bad = bls.SignatureSet(kps[0].sk.sign(b"other"), [cache.get(1)], msg)
@@ -68,6 +97,114 @@ def test_untagged_pubkeys_use_legacy_packing(cache_and_keys):
     legacy = [bls.SignatureSet(kps[0].sk.sign(msg), [raw_pk], msg)]
     assert bls.verify_signature_sets(legacy, backend="tpu", seed=1)
     assert not _last_marshal()["indexed"]
+
+
+def test_overflow_rows_are_the_twins_table_rows(cache_and_keys):
+    """Host-only: an untagged key's overflow row is, limb for limb, its
+    tagged twin's table row, indexed capacity + j; padding slots read
+    row 0; the flat and grouped layouts follow one rule."""
+    cache, kps = cache_and_keys
+    sig = kps[0].sk.sign(b"overflow rows")
+    sets = [
+        bls.SignatureSet(sig, [cache.get(0), _twin(kps[2])], b"a" * 32),
+        bls.SignatureSet(sig, [_twin(kps[5])], b"b" * 32),
+        bls.SignatureSet(sig, [cache.get(1)], b"c" * 32),
+    ]
+    m = tb._marshal(sets, allow_grouped=False)
+    tx, ty = (np.asarray(r) for r in m.table)
+    ox, oy = m.overflow
+    cap = tx.shape[0]
+    assert m.pubkeys is None and tb._shape_key(m) == "s4k2e8"
+    assert m.indices.tolist() == [
+        [1, cap], [cap + 1, 0], [2, 0], [0, 0]
+    ]
+    assert ox.shape == oy.shape == (8, 1, fb.NB)
+    for j, v in enumerate((2, 5)):
+        assert np.array_equal(ox[j], tx[v + 1])
+        assert np.array_equal(oy[j], ty[v + 1])
+    assert not ox[2:].any() and not oy[2:].any()
+
+    msg = b"d" * 32
+    grouped = [
+        bls.SignatureSet(sig, [_twin(kps[7])], msg),
+        bls.SignatureSet(sig, [cache.get(6), cache.get(3)], msg),
+    ]
+    g = tb._marshal(grouped)
+    assert g.grouped and tb._shape_key(g) == "g1x2k2e8"
+    assert g.indices.tolist() == [[[cap, 0], [7, 4]]]
+    assert np.array_equal(g.overflow[0][0], tx[8])
+    assert np.array_equal(g.overflow[1][0], ty[8])
+
+
+def test_sentinel_batch_takes_the_table(cache_and_keys):
+    """Host-only: tagged sets plus the canary's untagged sentinel take
+    the table path with one overflow slot; a batch with no tagged key
+    (the canary pair) stays packed. The slot counter says which."""
+    cache, kps = cache_and_keys
+    sig = kps[0].sk.sign(b"sentinel batch")
+    sentinel, invalid = canary.bls_sentinels()
+    tagged = [
+        bls.SignatureSet(sig, [cache.get(i), cache.get(i + 1)], bytes([i]))
+        for i in range(3)
+    ]
+    before = _slots()
+    m = tb._marshal(tagged + [sentinel], allow_grouped=False)
+    assert m.table is not None and m.pubkeys is None
+    assert m.indices[3, 0] == m.table[0].shape[0]
+    after = _slots()
+    assert after["table"] - before["table"] == 6
+    assert after["overflow"] - before["overflow"] == 1
+    assert after["packed"] == before["packed"]
+
+    p = tb._marshal([sentinel, invalid], allow_grouped=False)
+    assert p.table is None and p.indices is None and p.overflow is None
+    assert p.pubkeys[0].shape == (4, 1, 1, fb.NB)
+    assert tb._shape_key(p) == "s4k1"
+    assert _slots()["packed"] - after["packed"] == 2
+
+
+def test_marshal_never_builds_a_table():
+    """Host-only: tagged keys whose cache has no table yet pack, and
+    the marshal leaves the table unbuilt; once built, the same batch
+    gathers from it."""
+    kps = [
+        bls.Keypair(bls.SecretKey.from_bytes((i + 11).to_bytes(32, "big")))
+        for i in range(2)
+    ]
+    cache = PubkeyCache()
+    cache.import_new(_State([kp.pk.to_bytes() for kp in kps]))
+    sig = kps[0].sk.sign(b"no table")
+    sets = [bls.SignatureSet(sig, [cache.get(0), cache.get(1)], b"e" * 32)]
+    m = tb._marshal(sets, allow_grouped=False)
+    assert m.table is None and m.pubkeys is not None
+    assert cache.ready_table() is None and cache._device_table is None
+
+    cache.device_table()
+    m = tb._marshal(sets, allow_grouped=False)
+    assert m.table is not None and m.indices[0, :2].tolist() == [1, 2]
+
+
+def test_appends_match_a_table_built_at_once():
+    """Host-only: appends within the capacity and past it read the same
+    device rows as a table built from all the keys in one go."""
+    pks = [
+        bls.Keypair(
+            bls.SecretKey.from_bytes((i + 21).to_bytes(32, "big"))
+        ).pk
+        for i in range(9)
+    ]
+    grown = DevicePubkeyTable()
+    grown.append(pks[:2])
+    grown.rows()
+    # keys 3-5 fit the 8-row capacity; keys 6-9 grow it to 16 rows
+    for lo, hi, cap in ((2, 5, 8), (5, 9, 16)):
+        grown.append(pks[lo:hi])
+        whole = DevicePubkeyTable()
+        whole.append(pks[:hi])
+        assert grown.count == whole.count == hi
+        for a, b in zip(grown.rows(), whole.rows()):
+            assert a.shape == (cap, 1, fb.NB)
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_multi_key_aggregate_through_table(cache_and_keys):

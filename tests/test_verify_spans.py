@@ -70,6 +70,7 @@ def test_pubkeys_span_names_the_path_and_slots():
     cache.import_new(
         NS(validators=[NS(pubkey=kp.pk.to_bytes()) for kp in kps])
     )
+    cache.device_table()
     msg = b"verify-spans pubkeys"
     agg = bls.aggregate_signatures([kp.sk.sign(msg) for kp in kps[:2]])
     tagged = [
@@ -80,7 +81,7 @@ def test_pubkeys_span_names_the_path_and_slots():
     (pubkeys,) = tracing.find(
         _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
     )
-    assert pubkeys["attrs"] == {"slots": 3, "path": "table"}
+    assert pubkeys["attrs"] == {"slots": 3, "path": "table", "overflow": 0}
 
     untagged = bls.PublicKey.from_bytes(kps[2].pk.to_bytes())
     mixed = tagged[:1] + [bls.SignatureSet(tagged[1].signature,
@@ -89,7 +90,14 @@ def test_pubkeys_span_names_the_path_and_slots():
     (pubkeys,) = tracing.find(
         _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
     )
-    assert pubkeys["attrs"] == {"slots": 3, "path": "packed"}
+    assert pubkeys["attrs"] == {"slots": 3, "path": "table", "overflow": 1}
+
+    bare = [bls.SignatureSet(tagged[1].signature, [untagged], msg)]
+    tb._marshal(bare, allow_grouped=False)
+    (pubkeys,) = tracing.find(
+        _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
+    )
+    assert pubkeys["attrs"] == {"slots": 1, "path": "packed", "overflow": 0}
 
 
 def test_guarded_attempt_nests_under_the_callers_span():
