@@ -17,6 +17,13 @@ Hot-path design (SURVEY §7 hard part 4, validator_pubkey_cache.rs:9-24):
     packs every slot. The chain builds the table when it starts on this
     backend; a marshal never builds it, and packs every slot while the
     cache has no complete table.
+  * a set wider than KEY_LANE_WIDTH keys (an Electra aggregate spans a
+    slot's committees: up to 31,250 keys at 1M validators) is cut into
+    key lanes of that width: the flat grid is (lane bucket, width) with
+    a lane -> set map, and the device folds the lane sums per set, so
+    the key axis is not padded to the widest set and one program serves
+    every vote split. A batch whose sets all fit one lane keeps the
+    one-lane-a-set grid and program.
   * message hash_to_g2 results are memoized — a slot's 30k attestation
     sets share ~committee-count distinct messages, so the cache collapses
     the per-set cost to a dict hit.
@@ -64,8 +71,9 @@ _MSG_CACHE_EVENTS = REGISTRY.counter_vec(
 )
 _PUBKEY_SLOTS = REGISTRY.counter_vec(
     "lighthouse_tpu_pubkey_slots_total",
-    "live pubkey slots marshalled, by where their limbs came from: the"
-    " HBM table, the batch's overflow rows, or packed key by key",
+    "pubkey slots marshalled: live ones by where their limbs came from"
+    " (the HBM table, the batch's overflow rows, or packed key by key),"
+    " and the grid's padding slots",
     ("path",),
 )
 
@@ -235,12 +243,12 @@ def _gather_pubkeys(table_x, table_y, over_x, over_y, indices):
 
 def _indexed_verify(
     use_pallas, msgs, sigs, table_x, table_y, over_x, over_y, indices,
-    key_mask, rand_bits, set_mask,
+    key_mask, rand_bits, set_mask, lane_map=None,
 ):
     """Gather pubkey limb rows by table index on device, then verify."""
     pks = _gather_pubkeys(table_x, table_y, over_x, over_y, indices)
     return _verify_impl(use_pallas)(
-        msgs, sigs, pks, key_mask, rand_bits, set_mask
+        msgs, sigs, pks, key_mask, rand_bits, set_mask, lane_map
     )
 
 
@@ -299,6 +307,11 @@ def _get_indexed_fn():
                 functools.partial(_indexed_verify, key[0])
             )
     return fn
+
+
+# keys a lane holds: a flat batch with a set wider than this splits each
+# set into lanes of at most this many keys, folded back per set on device
+KEY_LANE_WIDTH = 512
 
 
 def _bucket(n: int, minimum: int) -> int:
@@ -389,14 +402,15 @@ def _rlc_scalars(n: int, seed):
     return [1 + secrets.randbelow(top - 1) for _ in range(n)]
 
 
-def _table_for(sets):
+def _table_for(lanes):
     """(cache, DevicePubkeyTable) of the PubkeyCache that tags the
-    batch's first tagged pubkey, when that cache's table is built and
-    holds every key it does; else None (no tagged key, or no table yet:
-    a marshal never builds one). Keys that cache does not tag become
-    the batch's overflow rows."""
-    for s in sets:
-        for p in s.pubkeys:
+    batch's first tagged pubkey (`lanes`: each lane's pubkeys, None for
+    padding), when that cache's table is built and holds every key it
+    does; else None (no tagged key, or no table yet: a marshal never
+    builds one). Keys that cache does not tag become the batch's
+    overflow rows."""
+    for pks in lanes:
+        for p in pks or ():
             cache = tagging_cache(p)
             if cache is not None:
                 table = cache.ready_table()
@@ -420,6 +434,10 @@ class _Marshalled:
         "pubkeys",
         "s_bucket",
         "k_bucket",
+        # key lanes (flat batches with a set wider than KEY_LANE_WIDTH):
+        # the (L,) int32 lane -> set map and each set's (S,) int32 last
+        # lane, else None
+        "lane_map",
         # message-grouped layout (None/False when flat)
         "grouped",
         "group_mask",
@@ -457,8 +475,10 @@ def _marshal(sets, allow_grouped: bool = True) -> _Marshalled:
     (G distinct messages -> G+1 Miller loops instead of S+1; the
     committee-shaped attestation load has S/G >= 100). The per-set
     fallback path marshals with allow_grouped=False — per-set verdicts
-    need per-set pairs."""
-    if allow_grouped and _grouping_enabled():
+    need per-set pairs. A batch with a set wider than KEY_LANE_WIDTH
+    takes the flat layout's key lanes."""
+    wide = max(len(s.pubkeys) for s in sets) > KEY_LANE_WIDTH
+    if allow_grouped and not wide and _grouping_enabled():
         plan = _group_plan(sets)
         if plan is not None:
             return _marshal_grouped(sets, plan)
@@ -476,6 +496,7 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
     m.n_groups = G
     m.s_bucket = g_b * sg_b
     m.k_bucket = _bucket(max(len(s.pubkeys) for s in sets), 1)
+    m.lane_map = None
 
     with span("verify/marshal/points"):
         group_msgs = [_msg_affine(sets[ix[0]].message) for _, ix in groups]
@@ -497,18 +518,11 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
         m.set_mask = np.array(
             [i is not None for i in order], dtype=bool
         ).reshape(g_b, sg_b)
-        m.key_mask = np.array(
-            [
-                [False] * m.k_bucket
-                if i is None
-                else [True] * len(sets[i].pubkeys)
-                + [False] * (m.k_bucket - len(sets[i].pubkeys))
-                for i in order
-            ],
-            dtype=bool,
-        ).reshape(g_b, sg_b, m.k_bucket)
-
-        _marshal_pubkeys(m, sets, order, (g_b, sg_b))
+        lanes = [None if i is None else sets[i].pubkeys for i in order]
+        m.key_mask = _key_mask(lanes, m.k_bucket).reshape(
+            g_b, sg_b, m.k_bucket
+        )
+        _marshal_pubkeys(m, lanes, (g_b, sg_b), wide_sets=0)
         m.msgs = _pack_g2_affine(group_msgs)
         m.sigs = tuple(
             np.asarray(c).reshape(g_b, sg_b, 2, fb.NB)
@@ -517,15 +531,47 @@ def _marshal_grouped(sets, groups) -> _Marshalled:
     return m
 
 
+def _key_lanes(sets, s_bucket):
+    """(lanes, lane map or None, key bucket) of a flat batch. Sets of
+    at most KEY_LANE_WIDTH keys are one lane each, padded to the set
+    bucket, with a key bucket of the widest set: the layout and program
+    of a batch without key lanes. Otherwise every set is cut into runs
+    of KEY_LANE_WIDTH keys, one lane each, in set order, padded to a
+    power-of-two lane bucket; the lane map is the (L,) lane -> set map
+    (padding lanes -1) and each of the s_bucket sets' last lane
+    (padding sets -1)."""
+    widest = max(len(s.pubkeys) for s in sets)
+    if widest <= KEY_LANE_WIDTH:
+        lanes = [s.pubkeys for s in sets] + [None] * (s_bucket - len(sets))
+        return lanes, None, _bucket(widest, 1)
+    w = KEY_LANE_WIDTH
+    lanes, owner = [], []
+    last = np.full(s_bucket, -1, dtype=np.int32)
+    for i, s in enumerate(sets):
+        pks = list(s.pubkeys)
+        for lo in range(0, len(pks), w):
+            lanes.append(pks[lo:lo + w])
+            owner.append(i)
+        last[i] = len(lanes) - 1
+    pad = _bucket(len(lanes), 4) - len(lanes)
+    lane_set = np.array(owner + [-1] * pad, dtype=np.int32)
+    return lanes + [None] * pad, (lane_set, last), w
+
+
+def _key_mask(lanes, k_bucket):
+    """(len(lanes), k_bucket) bool: each lane's first len(keys) slots."""
+    n = np.array([len(pks) if pks else 0 for pks in lanes])
+    return np.arange(k_bucket)[None, :] < n[:, None]
+
+
 def _marshal_flat(sets) -> _Marshalled:
     n_sets = len(sets)
-    max_keys = max(len(s.pubkeys) for s in sets)
     m = _Marshalled()
     m.grouped = False
     m.n_groups = None
     m.group_mask = None
     m.s_bucket = _bucket(n_sets, 4)
-    m.k_bucket = _bucket(max_keys, 1)
+    lanes, m.lane_map, m.k_bucket = _key_lanes(sets, m.s_bucket)
 
     with span("verify/marshal/points"):
         msgs = [_msg_affine(s.message) for s in sets]
@@ -537,40 +583,34 @@ def _marshal_flat(sets) -> _Marshalled:
         m.set_mask = np.array(
             [True] * n_sets + [False] * (m.s_bucket - n_sets), dtype=bool
         )
-        m.key_mask = np.array(
-            [
-                [True] * len(s.pubkeys)
-                + [False] * (m.k_bucket - len(s.pubkeys))
-                for s in sets
-            ]
-            + [[False] * m.k_bucket] * (m.s_bucket - n_sets),
-            dtype=bool,
-        )
-
-        order = list(range(n_sets)) + [None] * (m.s_bucket - n_sets)
-        _marshal_pubkeys(m, sets, order, (m.s_bucket,))
+        m.key_mask = _key_mask(lanes, m.k_bucket)
+        wide = sum(len(s.pubkeys) > KEY_LANE_WIDTH for s in sets)
+        _marshal_pubkeys(m, lanes, (len(lanes),), wide_sets=wide)
         m.msgs = _pack_g2_affine(msgs)
         m.sigs = _pack_g2_affine(sigs)
     return m
 
 
-def _marshal_pubkeys(m, sets, order, lanes_shape):
-    """Fill a marshal's pubkey slots for its lanes (`order`: the set
-    index of each lane, None for padding; `lanes_shape` the layout's
-    lane grid). A batch with a tagged key takes the table path: indices
-    into the HBM table, the untagged keys as overflow rows in a
-    power-of-two bucket of at least 8, so a batch with up to 8 of them
-    (the canary sentinel) reaches one program. A batch with no tagged
-    key packs every slot on the host."""
-    live = sum(len(s.pubkeys) for s in sets)
-    with span("verify/marshal/pubkeys", slots=live) as sp:
-        found = _table_for(sets)
+def _marshal_pubkeys(m, lanes, lanes_shape, wide_sets):
+    """Fill a marshal's pubkey slots for its lanes (`lanes`: each lane's
+    pubkeys, None for padding; `lanes_shape` the layout's lane grid;
+    `wide_sets` the sets cut into several lanes). A batch with a tagged
+    key takes the table path: indices into the HBM table, the untagged
+    keys as overflow rows in a power-of-two bucket of at least 8, so a
+    batch with up to 8 of them (the canary sentinel) reaches one
+    program. A batch with no tagged key packs every slot on the host."""
+    live = sum(len(pks) for pks in lanes if pks)
+    _PUBKEY_SLOTS.labels("padding").inc(len(lanes) * m.k_bucket - live)
+    n_lanes = sum(1 for pks in lanes if pks)
+    with span(
+        "verify/marshal/pubkeys", slots=live, lanes=n_lanes,
+        wide_sets=wide_sets,
+    ) as sp:
+        found = _table_for(lanes)
         if found is None:
             pk_flat = []
-            for i in order:
-                row = [] if i is None else [
-                    G1_GROUP.to_affine(p.point) for p in sets[i].pubkeys
-                ]
+            for pks in lanes:
+                row = [G1_GROUP.to_affine(p.point) for p in pks or ()]
                 pk_flat += row + [None] * (m.k_bucket - len(row))
             shape = lanes_shape + (m.k_bucket, 1, fb.NB)
             m.table = m.indices = m.overflow = None
@@ -582,11 +622,7 @@ def _marshal_pubkeys(m, sets, order, lanes_shape):
             tag(sp, path="packed", overflow=0)
             return
         cache, table = found
-        m.table, indices, over = table.index_lanes(
-            [None if i is None else sets[i].pubkeys for i in order],
-            m.k_bucket,
-            cache,
-        )
+        m.table, indices, over = table.index_lanes(lanes, m.k_bucket, cache)
         m.indices = indices.reshape(lanes_shape + (m.k_bucket,))
         m.overflow = limb_rows(over, _bucket(len(over), 8))
         m.pubkeys = None
@@ -622,11 +658,14 @@ def compile_ahead(sets) -> float:
 
 def _shape_key(m) -> str:
     """Shape-bucket string for the compile ledger: the (set, key)
-    bucket class this marshal compiled/hit, and on the table path the
-    overflow-row bucket."""
+    bucket class this marshal compiled/hit (with key lanes, the set
+    bucket, the lane bucket and the lane width), and on the table path
+    the overflow-row bucket."""
     if m.grouped:
         g_b, sg_b = m.set_mask.shape
         key = f"g{g_b}x{sg_b}k{m.k_bucket}"
+    elif m.lane_map is not None:
+        key = f"s{m.s_bucket}l{m.lane_map[0].shape[0]}k{m.k_bucket}"
     else:
         key = f"s{m.s_bucket}k{m.k_bucket}"
     if m.table is not None:
@@ -721,13 +760,15 @@ def _program(m, rand_bits):
             m.msgs, m.sigs, m.pubkeys, m.key_mask, rand_bits,
             m.set_mask, m.group_mask,
         )
+    lanes = () if m.lane_map is None else (m.lane_map,)
     if m.table is not None:
         return "verify_indexed", _get_indexed_fn(), (
             m.msgs, m.sigs, *m.table, *m.overflow, m.indices,
-            m.key_mask, rand_bits, m.set_mask,
+            m.key_mask, rand_bits, m.set_mask, *lanes,
         )
     return "verify", _get_fn(), (
-        m.msgs, m.sigs, m.pubkeys, m.key_mask, rand_bits, m.set_mask
+        m.msgs, m.sigs, m.pubkeys, m.key_mask, rand_bits, m.set_mask,
+        *lanes,
     )
 
 
@@ -850,11 +891,11 @@ def verify_signature_set_batches_tpu(
 
 def _indexed_individual(
     msgs, sigs, table_x, table_y, over_x, over_y, indices, key_mask,
-    set_mask,
+    set_mask, lane_map=None,
 ):
     pks = _gather_pubkeys(table_x, table_y, over_x, over_y, indices)
     return batch_verify.verify_signature_sets_individual(
-        msgs, sigs, pks, key_mask, set_mask
+        msgs, sigs, pks, key_mask, set_mask, lane_map
     )
 
 
@@ -899,14 +940,15 @@ def verify_signature_sets_tpu_individual(
     plain_fn, indexed_fn = _get_individual_fns()
     CALL_COUNTS["individual"] += 1
     shape = _shape_key(m)
+    lanes = () if m.lane_map is None else (m.lane_map,)
     if m.table is not None:
         name, fn, args = "verify_individual_indexed", indexed_fn, (
             m.msgs, m.sigs, *m.table, *m.overflow, m.indices,
-            m.key_mask, m.set_mask,
+            m.key_mask, m.set_mask, *lanes,
         )
     else:
         name, fn, args = "verify_individual", plain_fn, (
-            m.msgs, m.sigs, m.pubkeys, m.key_mask, m.set_mask
+            m.msgs, m.sigs, m.pubkeys, m.key_mask, m.set_mask, *lanes
         )
     compile_s = _compile_ahead(name, fn, args, shape)
 

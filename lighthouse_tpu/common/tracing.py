@@ -43,7 +43,9 @@ Span taxonomy (the instrumented call tree):
         verify/marshal/pack       mask/limb packing + table indices
           verify/marshal/pubkeys  pubkey slots: table indices plus
                                   overflow rows, or per-key packing
-                                  (attrs path, slots, overflow)
+                                  (attrs path, slots, overflow, lanes,
+                                  wide_sets: the live key lanes and the
+                                  sets cut into several)
       verify/compile              a cold bucket's compile-ahead
       verify/rlc_sample           RLC scalar sampling
       verify/device               device dispatch + verdict force

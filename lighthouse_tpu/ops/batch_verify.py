@@ -15,7 +15,10 @@ is infinity is masked out (exact: e(inf, H) == 1); a forged signature
 still breaks the identity through the signature-sum pair.
 
 Shapes: G2 affine = pair of (..., 2, NB) bundles; G1 affine = pair of
-(..., 1, NB); pubkeys = ((S, K, 1, NB), (S, K, 1, NB)).
+(..., 1, NB); pubkeys = ((S, K, 1, NB), (S, K, 1, NB)), or with key
+lanes ((L, W, 1, NB), ...) plus a lane map ((L,) int32 lane -> set,
+(S,) int32 last lane of each set), folded into the S sets on device
+(`aggregate_pubkeys`).
 
 Host-side policy (empty-set rejection, infinity-pubkey rejection, point
 decompression, subgroup checks, RLC sampling) lives in `lighthouse_tpu.bls`.
@@ -50,12 +53,43 @@ def _expand0(pt):
     return tuple(c[None] for c in pt)
 
 
-def aggregate_pubkeys(pubkeys_g1_aff, key_mask):
+def aggregate_pubkeys(pubkeys_g1_aff, key_mask, lane_map=None):
     """(S, K) affine G1 + mask -> (S,) projective aggregate per set
     (log-depth tree fold over the key axis, complete-formula plane).
-    from_affine already maps masked-out lanes to the identity."""
+    from_affine already maps masked-out lanes to the identity.
+
+    With a `lane_map` the leading axis holds key lanes, not sets:
+    (L, W) keys, lane l a run of one set's keys; each lane is summed as
+    above, then `fold_lanes` folds them into one sum per set."""
     pts = curve.PG1.from_affine(pubkeys_g1_aff, key_mask)
-    return curve.PG1.sum_axis(pts, axis=1)
+    agg = curve.PG1.sum_axis(pts, axis=1)
+    if lane_map is None:
+        return agg
+    return fold_lanes(agg, *lane_map)
+
+
+def fold_lanes(lane_aggs, lane_set, last_lane):
+    """(L,) projective lane sums -> (S,) per-set sums, given the (L,)
+    int32 lane -> set map and each set's (S,) int32 last lane. The lanes
+    of a set are contiguous (the host lays them out set by set; padding
+    lanes map to -1): a segmented log-depth scan leaves at each lane the
+    sum of its set's lanes up to it, and each set reads its last lane. A
+    set with no lane (last lane -1) reads the identity."""
+    import jax
+
+    G = curve.PG1
+    L = lane_set.shape[0]
+    lanes = jnp.arange(L, dtype=jnp.int32)
+
+    def step(k, acc):
+        d = jnp.left_shift(1, k)
+        prev = tuple(jnp.roll(c, d, axis=0) for c in acc)
+        same = (lanes >= d) & (jnp.roll(lane_set, d) == lane_set)
+        return G.select(same, G.add(acc, prev), acc)
+
+    acc = jax.lax.fori_loop(0, (L - 1).bit_length(), step, lane_aggs)
+    picked = tuple(jnp.take(c, jnp.maximum(last_lane, 0), axis=0) for c in acc)
+    return G.select(last_lane >= 0, picked, G.identity_like(picked))
 
 
 def rlc_combined_signature(sigs_g2_aff, rand_bits, set_mask):
@@ -92,13 +126,14 @@ def _assemble_pairs(
 
 
 def miller_inputs(
-    msgs_g2_aff, sigs_g2_aff, pubkeys_g1_aff, key_mask, rand_bits, set_mask
+    msgs_g2_aff, sigs_g2_aff, pubkeys_g1_aff, key_mask, rand_bits, set_mask,
+    lane_map=None,
 ):
     """Build the (S+1)-pair multi-pairing inputs; shared with the sharded
     path. The `trace/*` spans attribute JAX TRACE time per stage — they
     fire once per (re)compile of the enclosing jit, not per dispatch."""
     with span("trace/pubkey_aggregation"):
-        agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask)
+        agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask, lane_map)
     with span("trace/rlc_ladder_g1"):
         agg_pk_r = wl.ladder(curve.PG1, agg_pk, rand_bits)
     pk_aff = curve.PG1.to_affine(agg_pk_r)
@@ -116,12 +151,15 @@ def verify_signature_sets(
     key_mask,
     rand_bits,
     set_mask,
+    lane_map=None,
 ):
     """One-shot batched verification of S signature sets on one chip.
-    Returns a scalar bool — True iff every real set verifies."""
+    Returns a scalar bool — True iff every real set verifies. With
+    `lane_map`, pubkeys and key_mask are (L, W) key lanes folded into
+    the S sets (`aggregate_pubkeys`)."""
     g1_side, g2_side, pair_mask = miller_inputs(
         msgs_g2_aff, sigs_g2_aff, pubkeys_g1_aff, key_mask, rand_bits,
-        set_mask,
+        set_mask, lane_map,
     )
     return pairing.multi_pairing_is_one(g1_side, g2_side, pair_mask)
 
@@ -315,6 +353,7 @@ def verify_signature_sets_individual(
     pubkeys_g1_aff,
     key_mask,
     set_mask,
+    lane_map=None,
 ):
     """Per-set verdicts in ONE device call (the batch-failure fallback —
     SURVEY §7 hard part 5, attestation batch.rs:115-131 semantics without
@@ -329,7 +368,7 @@ def verify_signature_sets_individual(
     <=2-call bound forbids) and the final exponentiation is
     batched per set. Returns a (S,) bool array (padding lanes True)."""
     S = set_mask.shape[0]
-    agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask)
+    agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask, lane_map)
     pk_x, pk_y, pk_inf = curve.PG1.to_affine(agg_pk)
 
     neg_g1 = (
@@ -488,6 +527,7 @@ def miller_inputs_pallas(
     key_mask,
     rand_bits,
     set_mask,
+    lane_map=None,
     block_b: int = 128,
     interpret: bool = False,
 ):
@@ -500,7 +540,8 @@ def miller_inputs_pallas(
     bits_t = jnp.transpose(rand_bits).astype(jnp.int32)  # (64, S)
 
     # ---- G1: aggregate per set (XLA fold), then the pallas ladder
-    agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask)  # (S,) projective
+    # (S,) projective
+    agg_pk = aggregate_pubkeys(pubkeys_g1_aff, key_mask, lane_map)
     agg_t = tuple(tf.from_batchlead(c) for c in agg_pk)
     agg_t = _pad_lanes_projective(agg_t, block_b, tcurve.TPG1)
     padded = agg_t[0].shape[-1] - agg_pk[0].shape[0]
@@ -536,6 +577,7 @@ def verify_signature_sets_pallas(
     key_mask,
     rand_bits,
     set_mask,
+    lane_map=None,
     block_b: int = 128,
     interpret: bool = False,
     tail: bool = False,
@@ -548,7 +590,7 @@ def verify_signature_sets_pallas(
     in-kernel (ops.pallas_tail) — without it they stay on XLA."""
     g1_side, g2_side, pair_mask = miller_inputs_pallas(
         msgs_g2_aff, sigs_g2_aff, pubkeys_g1_aff, key_mask, rand_bits,
-        set_mask, block_b=block_b, interpret=interpret,
+        set_mask, lane_map, block_b=block_b, interpret=interpret,
     )
     return _pairs_to_verdict_pallas(
         g1_side, g2_side, pair_mask, block_b=block_b,

@@ -81,7 +81,10 @@ def test_pubkeys_span_names_the_path_and_slots():
     (pubkeys,) = tracing.find(
         _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
     )
-    assert pubkeys["attrs"] == {"slots": 3, "path": "table", "overflow": 0}
+    assert pubkeys["attrs"] == {
+        "slots": 3, "path": "table", "overflow": 0, "lanes": 2,
+        "wide_sets": 0,
+    }
 
     untagged = bls.PublicKey.from_bytes(kps[2].pk.to_bytes())
     mixed = tagged[:1] + [bls.SignatureSet(tagged[1].signature,
@@ -90,14 +93,71 @@ def test_pubkeys_span_names_the_path_and_slots():
     (pubkeys,) = tracing.find(
         _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
     )
-    assert pubkeys["attrs"] == {"slots": 3, "path": "table", "overflow": 1}
+    assert pubkeys["attrs"] == {
+        "slots": 3, "path": "table", "overflow": 1, "lanes": 2,
+        "wide_sets": 0,
+    }
 
     bare = [bls.SignatureSet(tagged[1].signature, [untagged], msg)]
     tb._marshal(bare, allow_grouped=False)
     (pubkeys,) = tracing.find(
         _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
     )
-    assert pubkeys["attrs"] == {"slots": 1, "path": "packed", "overflow": 0}
+    assert pubkeys["attrs"] == {
+        "slots": 1, "path": "packed", "overflow": 0, "lanes": 1,
+        "wide_sets": 0,
+    }
+
+
+def _slots():
+    fam = REGISTRY.get("lighthouse_tpu_pubkey_slots_total")
+    return {
+        path: fam.labels(path).value
+        for path in ("table", "overflow", "packed", "padding")
+    }
+
+
+@pytest.mark.parametrize(
+    "width, lanes, wide_sets, shape, padding",
+    [
+        # every set fits a lane: one lane a set, the s4k4 grid of today
+        (4, 3, 0, "s4k4e8", 4 * 4 - 8),
+        # 2-key lanes: the 3- and 4-key sets take two each; 8 lanes
+        (2, 5, 2, "s4l8k2e8", 8 * 2 - 8),
+    ],
+)
+def test_pubkeys_span_and_counter_name_the_lanes(
+    width, lanes, wide_sets, shape, padding, monkeypatch
+):
+    monkeypatch.setattr(tb, "KEY_LANE_WIDTH", width)
+    kps = bls.interop_keypairs(4)
+    cache = PubkeyCache()
+    cache.import_new(
+        NS(validators=[NS(pubkey=kp.pk.to_bytes()) for kp in kps])
+    )
+    cache.device_table()
+    msg = b"verify-spans lanes"
+    sets = [
+        bls.SignatureSet(
+            bls.aggregate_signatures([kp.sk.sign(msg) for kp in kps[:k]]),
+            [cache.get(v) for v in range(k)], msg,
+        )
+        for k in (1, 3, 4)
+    ]
+    before = _slots()
+    m = tb._marshal(sets, allow_grouped=False)
+    after = _slots()
+    (pubkeys,) = tracing.find(
+        _newest("verify/marshal/pack"), "verify/marshal/pubkeys"
+    )
+    assert pubkeys["attrs"] == {
+        "slots": 8, "path": "table", "overflow": 0, "lanes": lanes,
+        "wide_sets": wide_sets,
+    }
+    assert tb._marshal_attrs(m)["shape"] == shape
+    assert {k: after[k] - before[k] for k in after} == {
+        "table": 8, "overflow": 0, "packed": 0, "padding": padding,
+    }
 
 
 def test_guarded_attempt_nests_under_the_callers_span():
